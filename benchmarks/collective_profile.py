@@ -5,6 +5,7 @@
 """
 import os
 
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU simulation: never take an accelerator
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse

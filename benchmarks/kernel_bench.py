@@ -121,7 +121,7 @@ def run(trace_path: str = "") -> None:
     from repro.kernels import fixedpoint_matmul, pack_weight
 
     pw = pack_weight(wkn[:256, :256], 2, 2)
-    y = fixedpoint_matmul(x[:, :256], pw, 2, n_bits=2, n_out=256)
+    y = fixedpoint_matmul(x[:, :256], pw, 2, n_bits=2, n_out=256, interpret=True)
     qw = core.quantize(wkn[:256, :256], core.delta_from_f(2), 2)
     err = float(jnp.max(jnp.abs(y - x[:, :256] @ qw)))
     emit("fixedpoint_matmul_exactness", 0.0, f"max_abs_err_vs_quantized_float={err:.2e}")
@@ -181,8 +181,8 @@ def run_fused_kernel_bench() -> None:
     n_blocks = B * max_blocks + 1
     ks = jax.random.split(jax.random.PRNGKey(3), 5)
     q = jax.random.normal(ks[0], (B, T, K, G, hd))
-    k_pool = jax.random.normal(ks[1], (n_blocks, block, K, hd))
-    v_pool = jax.random.normal(ks[2], (n_blocks, block, K, hd))
+    k_pool = jax.random.normal(ks[1], (n_blocks, K, block, hd))
+    v_pool = jax.random.normal(ks[2], (n_blocks, K, block, hd))
     perm = jax.random.permutation(ks[3], jnp.arange(1, n_blocks))[: B * max_blocks]
     bt = perm.reshape(B, max_blocks).astype(jnp.int32)
     pos0 = jax.random.randint(ks[4], (B,), 0, max_blocks * block).astype(jnp.int32)
@@ -206,13 +206,14 @@ def run_fused_kernel_bench() -> None:
     # against the quantized ref oracle (must be exact to kernel tolerance)
     # and report the drift vs the bf16-pool answer (accuracy cost of the
     # bits, gated at serve level by run_kv_quant_bench)
-    from repro.models.attention import KV_QMAX, block_scale_exp, pack_int4, quantize_fixed
+    from repro.core.packing import pack_int
+    from repro.models.attention import KV_QMAX, block_scale_exp, quantize_fixed
 
     def _quant_pool(pool, bits):
         qmax = KV_QMAX[bits]
-        e = block_scale_exp(pool[:, 0], qmax)  # (n_blocks, K)
-        q = quantize_fixed(pool, e[:, None, :], qmax)
-        return (pack_int4(q) if bits == 4 else q), e
+        e = block_scale_exp(pool[:, :, 0], qmax)  # (n_blocks, K), head-major pool
+        q = quantize_fixed(pool, e[..., None], qmax)
+        return (pack_int(q, 4) if bits == 4 else q), e
 
     drifts = {}
     for bits in (8, 4):

@@ -113,10 +113,12 @@ def symog_init(params: Any, cfg: SymogConfig) -> SymogState:
             # one Δ per expert, over EVERY leading dim: an unstacked stack
             # (E,D,F) gets f (E,); a scan-stacked stack (L,E,D,F) gets
             # (L,E) so each layer's experts keep their own exponent.
-            lead = w.shape[:-2]
-            w2 = w.reshape((-1,) + w.shape[-2:])
-            f, _ = jax.vmap(lambda e: optimal_f(e, cfg.n_bits, cfg.f_min, cfg.f_max))(w2)
-            return f.reshape(lead).astype(jnp.int32)
+            # (vmap per leading dim rather than flattening them: a reshape
+            # across a sharded expert axis would gather the whole stack)
+            search = lambda e: optimal_f(e, cfg.n_bits, cfg.f_min, cfg.f_max)[0]  # noqa: E731
+            for _ in range(w.ndim - 2):
+                search = jax.vmap(search)
+            return search(w).astype(jnp.int32)
         f, _ = optimal_f(w, cfg.n_bits, cfg.f_min, cfg.f_max)
         return jnp.asarray(f, jnp.int32)
 

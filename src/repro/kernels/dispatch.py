@@ -23,7 +23,7 @@ Attention backends (``set_attention_backend`` / ``REPRO_ATTN_BACKEND``):
                       loop; nothing materializes the logical cache view.
   'fused-interpret' — the same kernel under the Pallas interpreter (CI
                       parity against the composed path on CPU).
-  'composed'        — paged_gather → mask → dense attention in plain XLA:
+  'composed'        — gather_logical → mask → dense attention in plain XLA:
                       the reference implementation the kernel is tested
                       against (models/attention.py).
 
@@ -32,12 +32,19 @@ fallback elsewhere ('dense' / 'composed').  ``ServeEngine`` pins the
 resolved values at construction and restores the globals around every
 jitted call, so a ``set_*_backend()`` after construction can never desync
 a cached trace (DESIGN.md §4).
+
+``shard_kernel`` is how every kernel call meets an ambient mesh: XLA cannot
+partition a Mosaic kernel, so under a mesh of several devices the call
+rides a ``jax.shard_map`` (DESIGN.md §12).
 """
 from __future__ import annotations
 
 import os
 
 import jax
+from jax.sharding import PartitionSpec as P
+
+from repro.nn.sharding import current_mesh
 
 PACKED_BACKENDS = ("auto", "pallas", "interpret", "unpack", "dense")
 ATTN_BACKENDS = ("auto", "fused", "fused-interpret", "composed")
@@ -83,3 +90,22 @@ def resolve_attention_backend() -> str:
     if _attn_backend != "auto":
         return _attn_backend
     return "fused" if jax.default_backend() == "tpu" else "composed"
+
+
+def shard_kernel(call, *args, in_specs=None, out_specs=P()):
+    """``call(*args)`` for a Pallas kernel call under the ambient mesh.
+
+    Off-mesh (or on one device) this is a plain call.  Under a mesh of
+    several devices XLA refuses to partition a Mosaic kernel — even over
+    replicated operands — so the call runs inside a ``jax.shard_map``:
+    over ``in_specs``/``out_specs`` where the caller has a per-shard split,
+    otherwise with every operand and the result replicated, so each device
+    runs the whole call on whole operands."""
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return call(*args)
+    if in_specs is None:
+        in_specs = tuple(P() for _ in args)
+    return jax.shard_map(
+        call, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )(*args)

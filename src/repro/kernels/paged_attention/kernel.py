@@ -13,10 +13,14 @@ over the POOL's blocks, so the logical view never exists anywhere.
 Grid and blocks (GQA kernel):
 
   grid = (B, K, max_blocks)          j innermost: scratch carries across j
-  q    (B, K, T·G, hd)   block (1, 1, T·G, hd)  index (b, kh, 0, 0)
-  k/v  (n_blocks, block, K, hd) block (1, block, 1, hd)
-                                     index (block_tables[b, j], 0, kh, 0)
-  out  (B, K, T·G, hd)   block (1, 1, T·G, hd)  written on the last j
+  q    (B, K, T·G, hd)   block (-, -, T·G, hd)  index (b, kh, 0, 0)
+  k/v  (n_blocks, K, block, hd) block (-, -, block, hd)
+                                     index (block_tables[b, j], kh, 0, 0)
+  out  (B, K, T·G, hd)   block (-, -, T·G, hd)  written on the last j
+
+The pools are head-major inside a physical block, so every block's two
+minor dims are whole (block, hd) tiles — the (8, 128) rule Mosaic holds
+every block to (``-`` marks a squeezed unit dim).
 
 ``block_tables`` (and the per-row first query position + window) ride as
 scalar-prefetch operands (PrefetchScalarGridSpec), so the index_map reads
@@ -32,10 +36,11 @@ masked logits at -1e30 before the max and exp'd terms zeroed so a fully
 masked block contributes nothing.  int8 fixed-point pools dequantize in
 the kernel (× 2^-KV_F, an exponent shift) — ``kv_scale`` is static on the
 pool dtype.  Per-block SYMOG pools (DESIGN.md §11) instead carry int32
-exponent leaves: the ``_quant`` kernel variants read each (block, head)'s
-exponent through a (1, 1)-block operand indexed by the SAME prefetched
-table as the data block, unpack packed int4 words with a lane concatenate,
-and dequantize with one exp2 multiply inside the loop.
+exponent leaves: the ``_quant`` kernel variants read the physical block's
+(1, K) exponent row through the SAME prefetched table as the data block
+and pick head kh with a masked lane sum, unpack packed int4 words with a
+lane concatenate (core.packing split planes), and dequantize with one
+exp2 multiply inside the loop.
 
 The online recurrence per block j (m running max, l denominator, o acc):
 
@@ -61,6 +66,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.packing import unpack_planes
+
 NEG_INF = -1e30  # matches the composed path's masked-logit fill
 
 
@@ -78,82 +85,69 @@ def _online_update(mask, s, v, m_ref, l_ref, acc_ref):
     )
 
 
+def _init_scratch(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
 def _finish(o_ref, l_ref, acc_ref):
     # l == 0 only for queries with no visible key (padded tail-prefill
     # rows whose output is garbage either way) — keep it finite.
     l = l_ref[...]
     l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)[None, None]
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _attn_kernel(bt_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
-                 m_ref, l_ref, acc_ref, *, block: int, nb: int, g: int,
-                 scale: float, cap: float, kv_scale: float):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    tg, hd = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[...].reshape(tg, hd).astype(jnp.float32)
-    k = k_ref[...].reshape(block, hd).astype(jnp.float32)
-    v = v_ref[...].reshape(block, hd).astype(jnp.float32)
-    if kv_scale != 1.0:  # int8 fixed-point pool: exponent-shift dequant
-        k = k * kv_scale
-        v = v * kv_scale
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if cap > 0:
-        s = jnp.tanh(s / cap) * cap
-
-    q_pos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (tg, 1), 0) // g
-    kv_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    mask = (kv_pos <= q_pos) & (q_pos - kv_pos < win_ref[0])
-    _online_update(mask, s, v, m_ref, l_ref, acc_ref)
-
-    @pl.when(j == nb - 1)
-    def _done():
-        _finish(o_ref, l_ref, acc_ref)
+def _qk(q, k):
+    """q (rows, d) · k (block, d)^T without materializing the transpose."""
+    return jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
 
 
-def _unpack_int4(words):
-    """Split-halves int4 unpack (see ref.unpack_int4): the low nibbles are
-    lanes [0, w) and the high nibbles lanes [w, 2w), so unpacking is one
-    lane-axis concatenate — Mosaic-friendly, no interleave reshuffle."""
-    x = words.astype(jnp.int32)
-    return jnp.concatenate([(x << 28) >> 28, x >> 4], axis=-1)
+def _dequant(words, exp, kv_bits):
+    """int8 mantissa words (int4: two per word) × 2^exp, exp a (1, 1) array."""
+    m = unpack_planes(words, 4) if kv_bits == 4 else words.astype(jnp.int32)
+    return m.astype(jnp.float32) * jnp.exp2(exp.astype(jnp.float32))
 
 
-def _attn_kernel_quant(bt_ref, pos_ref, win_ref, q_ref, k_ref, v_ref,
-                       ke_ref, ve_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                       block: int, nb: int, g: int, scale: float, cap: float,
-                       kv_bits: int):
-    """Per-block-scale variant: k/v arrive as int8 mantissa words (int4
-    packs two lanes per word) and ``ke/ve`` carry this (block, head)'s
-    power-of-two exponent — dequant is unpack + one exp2 multiply."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+def _head_exp(e_ref, kh):
+    """This head's exponent from the block's (1, K) exponent row, as (1, 1)."""
+    e = e_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, e.shape, 1)
+    return jnp.sum(jnp.where(lane == kh, e, 0), axis=1, keepdims=True)
+
+
+def _attn_kernel(bt_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, *rest,
+                 block: int, nb: int, g: int, scale: float, cap: float,
+                 kv_scale: float, kv_bits: int):
+    """GQA body.  Float / KV_F int8 pools scale by the static ``kv_scale``;
+    per-block-scale pools (``kv_bits`` 8 or 4) carry two exponent operands
+    ahead of the output and dequantize with this block's head exponent."""
+    if kv_bits:
+        ke_ref, ve_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+    b, kh, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
-    tg, hd = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[...].reshape(tg, hd).astype(jnp.float32)
-    kw = k_ref[...].reshape(block, k_ref.shape[3])
-    vw = v_ref[...].reshape(block, v_ref.shape[3])
-    if kv_bits == 4:
-        kw, vw = _unpack_int4(kw), _unpack_int4(vw)
-    k = kw.astype(jnp.float32) * jnp.exp2(ke_ref[0, 0].astype(jnp.float32))
-    v = vw.astype(jnp.float32) * jnp.exp2(ve_ref[0, 0].astype(jnp.float32))
+    tg = q_ref.shape[0]
+    q = q_ref[...].astype(jnp.float32)
+    if kv_bits:
+        k = _dequant(k_ref[...], _head_exp(ke_ref, kh), kv_bits)
+        v = _dequant(v_ref[...], _head_exp(ve_ref, kh), kv_bits)
+    else:
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
+        if kv_scale != 1.0:  # int8 fixed-point pool: exponent-shift dequant
+            k = k * kv_scale
+            v = v * kv_scale
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    s = _qk(q, k) * scale
     if cap > 0:
         s = jnp.tanh(s / cap) * cap
 
@@ -171,92 +165,84 @@ def paged_attention_padded(q, k_pool, v_pool, block_tables, pos0, window, *,
                            g: int, scale: float, cap: float, kv_scale: float,
                            k_exp=None, v_exp=None, kv_bits: int = 0,
                            interpret: bool = False):
-    """q (B, K, T·G, hd) float; k/v pools (n_blocks, block, K, hd) float or
+    """q (B, K, T·G, hd) float; k/v pools (n_blocks, K, block, hd) float or
     int8; block_tables (B, max_blocks) int32; pos0 (B,) int32 first query
     position per row (queries contiguous); window (1,) int32 (2^30 =
     unwindowed).  Returns (B, K, T·G, hd) f32-accumulated in q's dtype.
 
     Per-block-scale pools pass ``k_exp``/``v_exp`` (n_blocks, K) int32
     exponents plus ``kv_bits`` (8, or 4 for packed pools whose last dim is
-    hd/2); exponents ride as ordinary operands whose (1, 1) BlockSpec is
-    indexed through the same scalar-prefetched table as the data blocks."""
+    hd/2); each grid step reads its physical block's exponent row through
+    the same scalar-prefetched table as the data block."""
     B, K, tg, hd = q.shape
-    block = k_pool.shape[1]
+    block = k_pool.shape[2]
     nb = block_tables.shape[1]
-    quant = k_exp is not None
     hdw = k_pool.shape[3]  # hd, or hd//2 for packed int4 words
-    in_specs = [
-        pl.BlockSpec((1, 1, tg, hd), lambda b, kh, j, bt, pos, win: (b, kh, 0, 0)),
-        pl.BlockSpec(
-            (1, block, 1, hdw), lambda b, kh, j, bt, pos, win: (bt[b, j], 0, kh, 0)
-        ),
-        pl.BlockSpec(
-            (1, block, 1, hdw), lambda b, kh, j, bt, pos, win: (bt[b, j], 0, kh, 0)
-        ),
-    ]
+    quant = k_exp is not None
+    kv_spec = pl.BlockSpec(
+        (None, None, block, hdw), lambda b, kh, j, bt, pos, win: (bt[b, j], kh, 0, 0)
+    )
+    q_spec = pl.BlockSpec((None, None, tg, hd), lambda b, kh, j, bt, pos, win: (b, kh, 0, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_pool, v_pool]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda b, kh, j, bt, pos, win: (bt[b, j], kh)),
-            pl.BlockSpec((1, 1), lambda b, kh, j, bt, pos, win: (bt[b, j], kh)),
-        ]
-        operands += [k_exp, v_exp]
-        body = functools.partial(
-            _attn_kernel_quant, block=block, nb=nb, g=g, scale=scale, cap=cap,
-            kv_bits=kv_bits,
-        )
-    else:
-        body = functools.partial(
-            _attn_kernel, block=block, nb=nb, g=g, scale=scale, cap=cap,
-            kv_scale=kv_scale,
-        )
+        e_spec = pl.BlockSpec((None, 1, K), lambda b, kh, j, bt, pos, win: (bt[b, j], 0, 0))
+        in_specs += [e_spec, e_spec]
+        operands += [k_exp.reshape(-1, 1, K), v_exp.reshape(-1, 1, K)]
+    body = functools.partial(
+        _attn_kernel, block=block, nb=nb, g=g, scale=scale, cap=cap,
+        kv_scale=kv_scale, kv_bits=kv_bits if quant else 0,
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, K, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, tg, hd), lambda b, kh, j, bt, pos, win: (b, kh, 0, 0)
-        ),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((tg, 1), jnp.float32),
             pltpu.VMEM((tg, 1), jnp.float32),
             pltpu.VMEM((tg, hd), jnp.float32),
         ],
     )
+    name = f"paged_attention_int{kv_bits}" if quant else "paged_attention"
     return pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, tg, hd), q.dtype),
         interpret=interpret,
+        name=name,
     )(block_tables, pos0, window, *operands)
 
 
-def _mla_kernel(bt_ref, pos_ref, qe_ref, qr_ref, ckv_ref, kr_ref, o_ref,
-                m_ref, l_ref, acc_ref, *, block: int, nb: int, h: int,
-                scale: float, kv_scale: float):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+def _mla_kernel(bt_ref, pos_ref, qe_ref, qr_ref, ckv_ref, kr_ref, *rest,
+                block: int, nb: int, h: int, scale: float, kv_scale: float,
+                kv_bits: int):
+    """Absorbed-MLA body; per-block-scale pools (``kv_bits`` 8 or 4) carry
+    one scalar exponent per physical block and stream."""
+    if kv_bits:
+        ce_ref, re_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_scratch(m_ref, l_ref, acc_ref)
 
-    th, r = qe_ref.shape[1], qe_ref.shape[2]
-    rope = qr_ref.shape[2]
-    qe = qe_ref[...].reshape(th, r).astype(jnp.float32)
-    qr = qr_ref[...].reshape(th, rope).astype(jnp.float32)
-    ckv = ckv_ref[...].reshape(block, r).astype(jnp.float32)
-    kr = kr_ref[...].reshape(block, rope).astype(jnp.float32)
-    if kv_scale != 1.0:
-        ckv = ckv * kv_scale
-        kr = kr * kv_scale
+    th = qe_ref.shape[0]
+    qe = qe_ref[...].astype(jnp.float32)
+    qr = qr_ref[...].astype(jnp.float32)
+    if kv_bits:
+        ckv = _dequant(ckv_ref[...], ce_ref[...], kv_bits)
+        kr = _dequant(kr_ref[...], re_ref[...], kv_bits)
+    else:
+        ckv = ckv_ref[...].astype(jnp.float32)
+        kr = kr_ref[...].astype(jnp.float32)
+        if kv_scale != 1.0:
+            ckv = ckv * kv_scale
+            kr = kr * kv_scale
 
-    s = (
-        jnp.dot(qe, ckv.T, preferred_element_type=jnp.float32)
-        + jnp.dot(qr, kr.T, preferred_element_type=jnp.float32)
-    ) * scale
+    s = (_qk(qe, ckv) + _qk(qr, kr)) * scale
 
     q_pos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (th, 1), 0) // h
     kv_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
@@ -265,52 +251,7 @@ def _mla_kernel(bt_ref, pos_ref, qe_ref, qr_ref, ckv_ref, kr_ref, o_ref,
 
     @pl.when(j == nb - 1)
     def _done():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)[None]
-
-
-def _mla_kernel_quant(bt_ref, pos_ref, qe_ref, qr_ref, ckv_ref, kr_ref,
-                      ce_ref, re_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                      block: int, nb: int, h: int, scale: float, kv_bits: int):
-    """Per-block-scale MLA variant: both pools carry int8 mantissa words
-    (int4 packs two rank lanes per word) and a scalar power-of-two exponent
-    per physical block (the compressed stream has no head axis)."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    th, r = qe_ref.shape[1], qe_ref.shape[2]
-    rope = qr_ref.shape[2]
-    qe = qe_ref[...].reshape(th, r).astype(jnp.float32)
-    qr = qr_ref[...].reshape(th, rope).astype(jnp.float32)
-    cw = ckv_ref[...].reshape(block, ckv_ref.shape[2])
-    rw = kr_ref[...].reshape(block, kr_ref.shape[2])
-    if kv_bits == 4:
-        cw, rw = _unpack_int4(cw), _unpack_int4(rw)
-    ckv = cw.astype(jnp.float32) * jnp.exp2(ce_ref[0, 0].astype(jnp.float32))
-    kr = rw.astype(jnp.float32) * jnp.exp2(re_ref[0, 0].astype(jnp.float32))
-
-    s = (
-        jnp.dot(qe, ckv.T, preferred_element_type=jnp.float32)
-        + jnp.dot(qr, kr.T, preferred_element_type=jnp.float32)
-    ) * scale
-
-    q_pos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (th, 1), 0) // h
-    kv_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    mask = kv_pos <= q_pos
-    _online_update(mask, s, ckv, m_ref, l_ref, acc_ref)
-
-    @pl.when(j == nb - 1)
-    def _done():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)[None]
+        _finish(o_ref, l_ref, acc_ref)
 
 
 def paged_attention_mla_padded(q_eff, q_rope, ckv_pool, krope_pool,
@@ -328,41 +269,39 @@ def paged_attention_mla_padded(q_eff, q_rope, ckv_pool, krope_pool,
     nb = block_tables.shape[1]
     quant = ckv_exp is not None
     rw, ropew = ckv_pool.shape[2], krope_pool.shape[2]  # halved when packed
+    pool_spec = lambda w: pl.BlockSpec(  # noqa: E731
+        (None, block, w), lambda b, j, bt, pos: (bt[b, j], 0, 0))
     in_specs = [
-        pl.BlockSpec((1, th, r), lambda b, j, bt, pos: (b, 0, 0)),
-        pl.BlockSpec((1, th, rope), lambda b, j, bt, pos: (b, 0, 0)),
-        pl.BlockSpec((1, block, rw), lambda b, j, bt, pos: (bt[b, j], 0, 0)),
-        pl.BlockSpec((1, block, ropew), lambda b, j, bt, pos: (bt[b, j], 0, 0)),
+        pl.BlockSpec((None, th, r), lambda b, j, bt, pos: (b, 0, 0)),
+        pl.BlockSpec((None, th, rope), lambda b, j, bt, pos: (b, 0, 0)),
+        pool_spec(rw),
+        pool_spec(ropew),
     ]
     operands = [q_eff, q_rope, ckv_pool, krope_pool]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, 1), lambda b, j, bt, pos: (bt[b, j], 0)),
-            pl.BlockSpec((1, 1), lambda b, j, bt, pos: (bt[b, j], 0)),
-        ]
-        operands += [ckv_exp.reshape(-1, 1), kr_exp.reshape(-1, 1)]
-        body = functools.partial(
-            _mla_kernel_quant, block=block, nb=nb, h=h, scale=scale,
-            kv_bits=kv_bits,
-        )
-    else:
-        body = functools.partial(
-            _mla_kernel, block=block, nb=nb, h=h, scale=scale, kv_scale=kv_scale
-        )
+        e_spec = pl.BlockSpec((None, 1, 1), lambda b, j, bt, pos: (bt[b, j], 0, 0))
+        in_specs += [e_spec, e_spec]
+        operands += [ckv_exp.reshape(-1, 1, 1), kr_exp.reshape(-1, 1, 1)]
+    body = functools.partial(
+        _mla_kernel, block=block, nb=nb, h=h, scale=scale, kv_scale=kv_scale,
+        kv_bits=kv_bits if quant else 0,
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, th, r), lambda b, j, bt, pos: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, th, r), lambda b, j, bt, pos: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((th, 1), jnp.float32),
             pltpu.VMEM((th, 1), jnp.float32),
             pltpu.VMEM((th, r), jnp.float32),
         ],
     )
+    name = f"paged_attention_mla_int{kv_bits}" if quant else "paged_attention_mla"
     return pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, th, r), q_eff.dtype),
         interpret=interpret,
+        name=name,
     )(block_tables, pos0, *operands)

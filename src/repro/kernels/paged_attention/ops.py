@@ -1,7 +1,8 @@
 """Public wrappers: fused paged attention for decode / verify / tail-prefill.
 
 Callers hand the kernel the SAME operands the composed path consumes — the
-(B, T, K, G, hd) query block, the (n_blocks, block, ...) pools and the
+(B, T, K, G, hd) query block, the (n_blocks, K, block, hd) pools (MLA:
+(n_blocks, block, r)) and the
 (B, max_blocks) tables — plus the per-row FIRST query position; queries
 must be contiguous (q_pos[b, t] = pos0[b] + t), which every serving call
 site satisfies (decode T=1, speculative verify, bucketed tail prefill).
@@ -12,14 +13,15 @@ per-layer window scalar the gemma2/3 scan bodies carry.  ``kv_scale`` is
 the pool dequantization scale: 1.0 for float pools, 2^-KV_F for the int8
 fixed-point cache (static on the pool dtype — the caller passes it).
 
-Under an ambient mesh with a ``model`` axis (DESIGN.md §12) the public
-wrappers shard-map over KV heads when they divide: each model shard runs
-the SAME kernel on its local (B, T, K/m, G, hd) query slice against its
-local pool slice — attention is embarrassingly parallel across KV-head
-groups, so no collective appears; the o-projection's contraction psum is
-GSPMD's job outside this op.  MLA shards the H query heads instead and
-reads the (replicated) rank-space pools whole.  Heads that don't divide
-fall back to the unsharded call (GSPMD replicates).
+Under an ambient mesh with a ``model`` axis (``jax.set_mesh``, DESIGN.md
+§12) the public wrappers shard-map over KV heads when they divide: each
+model shard runs the SAME kernel on its local (B, T, K/m, G, hd) query
+slice against its local (n_blocks, K/m, block, hd) pool slice — attention
+is embarrassingly parallel across KV-head groups, so no collective
+appears; the o-projection's contraction psum is GSPMD's job outside this
+op.  MLA shards the H query heads instead and reads the (replicated)
+rank-space pools whole.  Heads that don't divide run the whole call on
+every device (``dispatch.shard_kernel``).
 """
 from __future__ import annotations
 
@@ -27,13 +29,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.paged_attention.kernel import (
     paged_attention_padded,
     paged_attention_mla_padded,
 )
+from repro.kernels.dispatch import shard_kernel
 from repro.nn.sharding import current_mesh, mesh_axis_size
 
 _NO_WINDOW = 2**30  # models.config.GLOBAL_WINDOW (no models import: layering)
@@ -63,10 +65,10 @@ def _paged_attention(q, k_pool, v_pool, block_tables, pos0, window, k_exp,
 def paged_attention(q, k_pool, v_pool, block_tables, pos0, *, scale: float,
                     cap: float = 0.0, window=None, kv_scale: float = 1.0,
                     k_scale_exp=None, v_scale_exp=None, kv_bits: int = 0,
-                    interpret: bool = True, out_dtype=None):
+                    interpret: bool = False, out_dtype=None):
     """Fused paged GQA/MQA attention.
 
-    q (B, T, K, G, hd); k/v pools (n_blocks, block, K, hd) float or int8;
+    q (B, T, K, G, hd); k/v pools (n_blocks, K, block, hd) float or int8;
     block_tables (B, max_blocks) int32 (trash block 0 for unused slots);
     pos0 (B,) int32.  ``window`` None, a Python int, or a traced int32
     scalar; ``cap`` the logit softcap (0 = off).  Masking, windowing and
@@ -84,22 +86,20 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos0, *, scale: float,
         scale=scale, cap=cap, kv_scale=kv_scale, kv_bits=kv_bits,
         interpret=interpret, out_dtype=out_dtype,
     )
-    mesh = current_mesh()
-    m = mesh_axis_size(mesh, "model")
+    args = (q, k_pool, v_pool, block_tables, pos0, w, k_scale_exp, v_scale_exp)
+    m = mesh_axis_size(current_mesh(), "model")
     if m > 1 and q.shape[2] % m == 0:
         # §12 head slicing: pools and queries split on the KV-head axis,
         # tables/positions/window replicated — each shard's kernel sees a
         # (B, T, K/m, G, hd) problem against its local pool slice
-        heads, exp = P(None, None, "model"), P(None, "model")
+        heads, pool = P(None, None, "model"), P(None, "model")
         in_specs = (
-            heads, heads, heads, P(), P(), P(),
-            exp if k_scale_exp is not None else P(),
-            exp if v_scale_exp is not None else P(),
+            heads, pool, pool, P(), P(), P(),
+            pool if k_scale_exp is not None else P(),
+            pool if v_scale_exp is not None else P(),
         )
-        return shard_map(
-            call, mesh=mesh, in_specs=in_specs, out_specs=heads, check_rep=False
-        )(q, k_pool, v_pool, block_tables, pos0, w, k_scale_exp, v_scale_exp)
-    return call(q, k_pool, v_pool, block_tables, pos0, w, k_scale_exp, v_scale_exp)
+        return shard_kernel(call, *args, in_specs=in_specs, out_specs=heads)
+    return shard_kernel(call, *args)
 
 
 @functools.partial(
@@ -127,7 +127,7 @@ def _paged_attention_mla(q_eff, q_rope, ckv_pool, krope_pool, block_tables,
 def paged_attention_mla(q_eff, q_rope, ckv_pool, krope_pool, block_tables,
                         pos0, *, scale: float, kv_scale: float = 1.0,
                         ckv_scale_exp=None, kr_scale_exp=None,
-                        kv_bits: int = 0, interpret: bool = True,
+                        kv_bits: int = 0, interpret: bool = False,
                         out_dtype=None):
     """Fused paged MLA absorbed decode (DESIGN.md §9).
 
@@ -142,17 +142,14 @@ def paged_attention_mla(q_eff, q_rope, ckv_pool, krope_pool, block_tables,
         scale=scale, kv_scale=kv_scale, kv_bits=kv_bits, interpret=interpret,
         out_dtype=out_dtype,
     )
-    mesh = current_mesh()
-    m = mesh_axis_size(mesh, "model")
+    args = (q_eff, q_rope, ckv_pool, krope_pool, block_tables, pos0, ckv_scale_exp,
+            kr_scale_exp)
+    m = mesh_axis_size(current_mesh(), "model")
     if m > 1 and q_eff.shape[2] % m == 0:
         # MLA has no KV-head axis — shard the H QUERY heads and read the
         # (replicated) rank-space pools whole on every shard (§12: their
         # bytes are already compressed by the low-rank factor)
         heads = P(None, None, "model")
         in_specs = (heads, heads, P(), P(), P(), P(), P(), P())
-        return shard_map(
-            call, mesh=mesh, in_specs=in_specs, out_specs=heads, check_rep=False
-        )(q_eff, q_rope, ckv_pool, krope_pool, block_tables, pos0,
-          ckv_scale_exp, kr_scale_exp)
-    return call(q_eff, q_rope, ckv_pool, krope_pool, block_tables, pos0,
-                ckv_scale_exp, kr_scale_exp)
+        return shard_kernel(call, *args, in_specs=in_specs, out_specs=heads)
+    return shard_kernel(call, *args)

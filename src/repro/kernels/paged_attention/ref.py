@@ -1,6 +1,6 @@
 """Pure-jnp oracle: the composed gather → mask → softmax paged attention.
 
-This mirrors models/attention.py's reference path (``paged_gather`` + the
+This mirrors models/attention.py's reference path (``gather_logical`` + the
 dense masked softmax) without importing it — kernels sit below models in
 the layering.  Parity tests assert the fused kernel against BOTH this
 oracle and the real composed layer code."""
@@ -9,26 +9,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.packing import unpack_int
+
 
 def gather_logical(pool, block_tables):
-    """(B, max_blocks·block, ...) logical view — what the kernel avoids."""
-    nb, block = pool.shape[:2]
-    flat = pool.reshape((nb * block,) + pool.shape[2:])
-    idx = (
-        block_tables[:, :, None] * block
-        + jnp.arange(block, dtype=jnp.int32)[None, None, :]
-    )
-    return flat[idx.reshape(block_tables.shape[0], -1)]
-
-
-def unpack_int4(packed):
-    """Split-halves int4 unpack: word i of a packed row holds lane i in its
-    low nibble and lane i + w/2 in its high (sign-carrying) nibble, so the
-    unpack is a lane-axis concatenate — no interleave reshuffle."""
-    x = packed.astype(jnp.int32)
-    lo = (x << 28) >> 28  # arithmetic shifts sign-extend the low nibble
-    hi = x >> 4
-    return jnp.concatenate([lo, hi], axis=-1)
+    """REFERENCE paged cache view (DESIGN.md §9): each row's token-major
+    logical cache (B, max_blocks·block, ...) — what the kernel avoids.  KV
+    pools are head-major inside a block, (n_blocks, K, block, hd); MLA
+    rank-space pools are (n_blocks, block, r).  Entries whose table slot is
+    trash (or beyond the row's position) are garbage — callers mask them
+    with kv_pos <= pos, exactly like the dense tail."""
+    g = pool[block_tables]  # (B, max_blocks, [K,] block, w)
+    if pool.ndim == 4:
+        g = g.swapaxes(2, 3)
+    return g.reshape(g.shape[:1] + (-1,) + g.shape[3:])
 
 
 def dequant_logical(pool, exp_leaf, block_tables, *, kv_bits):
@@ -36,9 +30,9 @@ def dequant_logical(pool, exp_leaf, block_tables, *, kv_bits):
     then every row of physical block p scaled by 2^exp_leaf[p] (per KV head
     where the exponent leaf carries a head axis)."""
     data = gather_logical(pool, block_tables)
-    if kv_bits == 4:
-        data = unpack_int4(data)
-    block = pool.shape[1]
+    if kv_bits == 4:  # core.packing split planes, two lanes per word
+        data = unpack_int(data, 4, 2 * data.shape[-1])
+    block = pool.shape[2] if pool.ndim == 4 else pool.shape[1]
     e = jnp.repeat(exp_leaf[block_tables], block, axis=1)  # (B, S[, K])
     scale = jnp.exp2(e.astype(jnp.float32))
     scale = scale[:, :, None] if e.ndim == 2 else scale[:, :, :, None]

@@ -64,4 +64,5 @@ def symog_update_2d(w, g, v, scalars, *, n_bits: int, interpret: bool = False):
             jax.ShapeDtypeStruct((R, C), jnp.float32),
         ],
         interpret=interpret,
+        name="symog_update",
     )(scalars, w, g, v)

@@ -1,8 +1,8 @@
 """Public wrapper: arbitrary-shape SYMOG fused update.
 
 Flattens/pads the parameter to the kernel's (R, 128) layout, runs the
-Pallas kernel, restores the original shape.  ``interpret=True`` on CPU
-(this container); on TPU the same call compiles to Mosaic.
+Pallas kernel, restores the original shape.  Compiles to Mosaic on TPU;
+pass ``interpret=True`` to run the Pallas interpreter (CPU tests).
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ _TILE = BLOCK_ROWS * LANE
 
 @functools.partial(jax.jit, static_argnames=("n_bits", "interpret"))
 def symog_update(w, g, v, *, delta, lam_eff, lr, mu, n_bits: int = 2,
-                 interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+                 interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Fused SYMOG step for one parameter tensor (any shape).
 
     Returns (w', v') with the semantics of ref.symog_update_ref.

@@ -1,4 +1,7 @@
 import os
+# a CPU simulation: never take an accelerator, in this process or the
+# per-cell children it starts (they inherit the environment)
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell from
@@ -81,7 +84,7 @@ def _lower_cell(arch: str, shape: str, multi_pod: bool, overrides=None,
     cell = SHAPES[shape]
     specs = input_specs(cfg, shape)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         if cell.kind == "train":
             # deepseek: bf16 momentum (optimizer-state compression) — fp32
             # momentum for 654B expert params alone is 10.2 GiB/chip
@@ -236,7 +239,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, quantized: bool = False) ->
 
     # logical (global, trip-count-exact) cost from the jaxpr
     t0 = time.time()
-    with mesh:  # model sharding constraints need the ambient mesh
+    with jax.set_mesh(mesh):  # model sharding constraints need the ambient mesh
         logical = jaxpr_cost(fn, *fargs)
     rec["trace_s"] = round(time.time() - t0, 1)
     rec["logical"] = logical

@@ -12,6 +12,8 @@ import math
 
 import jax
 
+from repro.nn.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -23,10 +25,10 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devices)} — "
             "run under dryrun.py (XLA_FLAGS=--xla_force_host_platform_device_count=512)"
         )
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return make_mesh(shape, axes, devices=devices[:need])
 
 
 def make_host_mesh():
     """Whatever this process actually has (tests / examples): (1,1) mesh."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))

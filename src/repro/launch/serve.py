@@ -50,7 +50,9 @@ import jax.numpy as jnp
 from repro import core
 from repro.checkpoint import CheckpointManager
 from repro.configs import ARCHS, get_config, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.lm import init_lm
+from repro.nn.sharding import make_mesh
 from repro.serve import (
     Request,
     ServeConfig,
@@ -299,6 +301,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.speculative and args.prefix_cache:
         ap.error("--speculative and --prefix-cache are mutually exclusive (DESIGN.md §8)")
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:
@@ -311,7 +314,7 @@ def main() -> None:
                 ap.error(f"--mesh must be DxM (e.g. 2x4) or 'dxm', got {args.mesh!r}")
         if d * m > jax.device_count():
             ap.error(f"--mesh {d}x{m} needs {d * m} devices, have {jax.device_count()}")
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         print(f"mesh: {d} data x {m} model over {d * m} "
               f"{jax.devices()[0].platform} devices")
 

@@ -13,8 +13,6 @@ from __future__ import annotations
 
 import argparse
 
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 
@@ -23,15 +21,17 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCHS, get_config, get_reduced
 from repro.data import SyntheticLM, SyntheticLMConfig
 from repro.distributed import StepTimeMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.shardings import data_shardings, state_shardings
 from repro.models.lm import init_lm
+from repro.nn.sharding import make_mesh
 from repro.train import init_train_state, make_train_step
 
 
 def parse_mesh(spec: str):
     dims = tuple(int(x) for x in spec.split("x"))
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    return jax.make_mesh(dims, names, devices=jax.devices()[: int(np.prod(dims))])
+    return make_mesh(dims, names)
 
 
 def main() -> None:
@@ -53,6 +53,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=20)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = parse_mesh(args.mesh)
 
@@ -71,7 +72,7 @@ def main() -> None:
     step_fn = make_train_step(cfg, tx, lr_sched, symog_cfg=symog_cfg,
                               accum_steps=args.accum, compute_dtype=compute_dtype)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params = init_lm(jax.random.PRNGKey(args.seed), cfg)
         state = init_train_state(params, tx, symog_cfg)
         st_sh = state_shardings(jax.eval_shape(lambda: state), mesh, cfg.sharding_profile)

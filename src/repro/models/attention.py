@@ -20,9 +20,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.packing import pack_int, unpack_int
 from repro.kernels.dispatch import resolve_attention_backend
 from repro.kernels.paged_attention import paged_attention, paged_attention_mla
-from repro.kernels.paged_attention.ref import unpack_int4
+from repro.kernels.paged_attention.ref import gather_logical
 from repro.models.layers import (
     apply_rope,
     dense_apply,
@@ -45,7 +46,8 @@ Q_CHUNK_DEFAULT = 1024  # chunk queries when T exceeds this
 #     leaf, calibrated once from the k/v vector at the block's first slot
 #     and never re-rounded (write-once-read-many), so hit/miss/chunked
 #     traces stay bit-identical.  int4 packs two lanes per int8 word
-#     (split halves: low nibbles = lanes [0, w/2), high = [w/2, w)).
+#     (core.packing split planes: low nibbles = lanes [0, w/2), high =
+#     [w/2, w) within each 128-word chunk).
 # ---------------------------------------------------------------------------
 KV_F = 5  # Δ = 2^-5: int8 range ±3.97, resolution 1/32 (post-norm k/v ~O(1))
 
@@ -86,18 +88,6 @@ def quantize_fixed(x, e, qmax):
     scale = jnp.exp2(-e.astype(jnp.float32))[..., None]
     q = jnp.round(x.astype(jnp.float32) * scale)
     return jnp.clip(q, -qmax, qmax).astype(jnp.int8)
-
-
-def pack_int4(x):
-    """Pack 2w int4 mantissas into w int8 words, split halves: word i holds
-    lane i in its low nibble and lane i + w in its high (sign) nibble — the
-    unpack is a lane concatenate (kernels.paged_attention.ref.unpack_int4)."""
-    w = x.shape[-1] // 2
-    x = x.astype(jnp.int32)
-    b = (x[..., :w] & 15) | (x[..., w:] << 4)
-    return jnp.where(b >= 128, b - 256, b).astype(jnp.int8)
-
-
 
 
 @dataclasses.dataclass(frozen=True)
@@ -284,44 +274,53 @@ def cache_update_rows(cache_leaf, new, pos, *, per_row: bool, axis: int = 1):
 
 
 # ---------------------------------------------------------------------------
-# paged KV cache: a (n_blocks, block, ...) pool shared by every slot, resolved
-# through per-slot block tables (DESIGN.md §6).  Block 0 is a reserved trash
-# block: evicted slots' tables are zeroed host-side, so their per-step writes
-# land in trash instead of needing a revert pass over the pool.
+# paged KV cache: an (n_blocks, ...) pool of token blocks shared by every
+# slot, resolved through per-slot block tables (DESIGN.md §6).  KV pools are
+# head-major inside a block, (n_blocks, K, block, hd), so each (block, hd)
+# is one whole tile of the fused kernel (§9); MLA rank-space pools are
+# (n_blocks, block, r).  Indices below are FLAT token indices
+# block_id·block + offset.  Block 0 is a reserved trash block: evicted
+# slots' tables are zeroed host-side, so their per-step writes land in
+# trash instead of needing a revert pass over the pool.
 # ---------------------------------------------------------------------------
+def paged_pool_shape(lead, n_phys: int, block: int, feat) -> Tuple[int, ...]:
+    """Shape of a paged pool leaf holding ``n_phys`` blocks of ``block``
+    tokens whose per-token entry is ``feat`` ((K, hd) or (r,)), after
+    ``lead`` leading (layer) dims."""
+    feat = tuple(feat)
+    if len(feat) == 2:
+        return tuple(lead) + (n_phys, feat[0], block, feat[1])
+    return tuple(lead) + (n_phys, block) + feat
+
+
+def pool_block(pool, axis: int = 0) -> int:
+    """Tokens per block of a paged pool leaf with ``axis`` leading dims."""
+    return pool.shape[axis + 2] if pool.ndim - axis == 4 else pool.shape[axis + 1]
+
+
 def paged_token_index(block_tables, pos, block: int):
     """Flat pool index of each row's write position.
 
     block_tables (B, max_blocks) physical block ids; pos (B,) int32 logical
-    positions.  Returns (B,) indices into the (n_blocks*block, ...) flat pool."""
+    positions.  Returns (B,) flat token indices (block_id·block + offset)."""
     b = jnp.arange(pos.shape[0], dtype=jnp.int32)
     return block_tables[b, pos // block] * block + pos % block
 
 
+def _pool_set(pool, idx, entries):
+    """Write token entries (N, *feat) at flat token indices idx (N,)."""
+    block = pool_block(pool)
+    bid, off = idx // block, idx % block
+    if pool.ndim == 4:  # head-major: the (bid, off) pair selects (K, hd)
+        return pool.at[bid, :, off].set(entries)
+    return pool.at[bid, off].set(entries)
+
+
 def paged_update(pool, new, idx):
-    """Scatter one decode step into the pool.  pool (n_blocks, block, ...);
-    new (B, ...) one entry per row; idx (B,) flat token indices (rows own
-    disjoint blocks, so only trash indices may collide — garbage either way)."""
-    nb, block = pool.shape[:2]
-    flat = pool.reshape((nb * block,) + pool.shape[2:])
-    flat = flat.at[idx].set(cache_write(new, pool.dtype))
-    return flat.reshape(pool.shape)
-
-
-def paged_gather(pool, block_tables):
-    """REFERENCE implementation of the paged cache view (DESIGN.md §9).
-
-    Materializes each row's logical cache: (B, max_blocks*block, ...).
-    Entries whose table slot is trash (or beyond the row's position) are
-    garbage — callers must mask them with kv_pos <= pos, exactly like the
-    dense tail.  The serving hot path fuses this gather into the
-    ``kernels.paged_attention`` online-softmax loop (the 'composed' backend
-    keeps this path as the oracle the kernel's parity tests target — see
-    tests/test_paged_attention.py)."""
-    nb, block = pool.shape[:2]
-    flat = pool.reshape((nb * block,) + pool.shape[2:])
-    idx = block_tables[:, :, None] * block + jnp.arange(block, dtype=jnp.int32)[None, None, :]
-    return flat[idx.reshape(block_tables.shape[0], -1)]
+    """Scatter one decode step into the pool.  new (B, ...) one entry per
+    row; idx (B,) flat token indices (rows own disjoint blocks, so only
+    trash indices may collide — garbage either way)."""
+    return _pool_set(pool, idx, cache_write(new, pool.dtype))
 
 
 def _pool_dequant_scale(pool) -> float:
@@ -332,15 +331,15 @@ def _pool_dequant_scale(pool) -> float:
 def paged_quant_update(pool, exp_leaf, new, idx):
     """Scatter entries into a SYMOG-quantized pool (DESIGN.md §11).
 
-    pool (n_blocks, block, ..., w) int8 mantissa words; exp_leaf (n_blocks,
-    ...) int32 per-block exponents; new (N, ..., width) float entries; idx
+    pool (n_blocks, [K,] block, w) int8 mantissa words; exp_leaf (n_blocks,
+    [K]) int32 per-block exponents; new (N, ..., width) float entries; idx
     (N,) flat token indices.  A block's exponent is calibrated ONCE, from
     the entry at its first slot (idx % block == 0) — non-start entries
     scatter their candidate exponent into the trash row instead, so a later
     chunk/tail/verify write never re-rounds KV an earlier pass committed.
     The exponent is a pure function of (params, token, position), which is
     what keeps hit, miss and chunked traces bit-identical."""
-    nb, block = pool.shape[:2]
+    block = pool_block(pool)
     bits = 4 if pool.shape[-1] * 2 == new.shape[-1] else 8
     qmax = KV_QMAX[bits]
     bid = idx // block
@@ -348,9 +347,8 @@ def paged_quant_update(pool, exp_leaf, new, idx):
     exp_leaf = exp_leaf.at[tgt].set(block_scale_exp(new, qmax))
     q = quantize_fixed(new, exp_leaf[bid], qmax)
     if bits == 4:
-        q = pack_int4(q)
-    flat = pool.reshape((nb * block,) + pool.shape[2:])
-    return flat.at[idx].set(q).reshape(pool.shape), exp_leaf
+        q = pack_int(q, 4)
+    return _pool_set(pool, idx, q), exp_leaf
 
 
 def _paged_write(cache, names, news, idx):
@@ -378,11 +376,11 @@ def _paged_read(cache, name, block_tables, dtype, width):
     exponent leaf carries one); KV_F/float leaves keep ``cache_read``."""
     sname = name + "_scale"
     if sname not in cache:
-        return cache_read(paged_gather(cache[name], block_tables), dtype)
-    data = paged_gather(cache[name], block_tables)
+        return cache_read(gather_logical(cache[name], block_tables), dtype)
+    data = gather_logical(cache[name], block_tables)
     if cache[name].shape[-1] * 2 == width:
-        data = unpack_int4(data)
-    block = cache[name].shape[1]
+        data = unpack_int(data, 4, width)
+    block = pool_block(cache[name])
     e = jnp.repeat(cache[sname][block_tables], block, axis=1)  # (B, S[, K])
     scale = jnp.exp2(e.astype(jnp.float32))[..., None]
     return (data.astype(jnp.float32) * scale).astype(dtype)
@@ -472,7 +470,7 @@ def attn_prefill_paged(
     if cfg.rope:
         q = apply_rope(q, positions, rope_base)
         k_new = apply_rope(k_new, positions, rope_base)
-    block = cache["k"].shape[1]
+    block = pool_block(cache["k"])
     pos_t = positions[0]  # (T,) global positions of the tail bucket
     idx = bt_row[pos_t // block] * block + pos_t % block
     idx = jnp.where(jnp.arange(T, dtype=jnp.int32) < seq_len, idx, 0)  # pads -> trash
@@ -560,7 +558,7 @@ def attn_verify_paged(
     if cfg.rope:
         q = apply_rope(q, positions, rope_base)
         k_new = apply_rope(k_new, positions, rope_base)
-    idx = verify_token_index(block_tables, positions, cache["k"].shape[1], valid)
+    idx = verify_token_index(block_tables, positions, pool_block(cache["k"]), valid)
     cache = _verify_scatter(cache, ("k", "v"), (k_new, v_new), idx)
     backend = resolve_attention_backend()
     if backend != "composed":
@@ -613,7 +611,7 @@ def attn_decode(p, x, cache, pos, *, cfg: AttnConfig, window=None, rope_base=100
         if block_tables is not None:
             if not per_row:
                 raise ValueError("paged decode requires per-row (B,) positions")
-            idx = paged_token_index(block_tables, positions[:, 0], cache["k"].shape[1])
+            idx = paged_token_index(block_tables, positions[:, 0], pool_block(cache["k"]))
             cache = _paged_write(cache, ("k", "v"), (k_new[:, 0], v_new[:, 0]), idx)
             backend = resolve_attention_backend()
             if backend != "composed":

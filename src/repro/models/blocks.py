@@ -96,7 +96,7 @@ def _mlp_cfg(cfg: ModelConfig) -> MLPConfig:
 
 
 def _ep_active(cfg: ModelConfig) -> bool:
-    """True when the ambient mesh (``with mesh:`` — readable mid-trace)
+    """True when the ambient mesh (``jax.set_mesh`` — readable mid-trace)
     carries the config's EP axes at total size > 1 and the expert count
     divides over them: the condition under which ``moe_impl='ep'`` actually
     dispatches the shard_map expert-parallel path (DESIGN.md §12).
@@ -161,21 +161,6 @@ def _tag(x, name: str):
     from jax.ad_checkpoint import checkpoint_name
 
     return checkpoint_name(x, name)
-
-
-@jax.custom_jvp
-def _barrier(x):
-    """Differentiable optimization_barrier: jax<0.5 has no AD rule for the
-    primitive, so train steps through scanned blocks would raise
-    NotImplementedError.  The barrier is the identity, so its tangent is
-    the identity (and the transpose of that linear JVP is too)."""
-    return jax.lax.optimization_barrier(x)
-
-
-@_barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    (x,), (t,) = primals, tangents
-    return _barrier(x), t
 
 
 # ---------------------------------------------------------------------------
@@ -277,7 +262,7 @@ def block_apply(
         # collective to rebuild the norm input (measured in §Perf it.2).
         # The barrier also pins the wire dtype: without it XLA hoists the
         # norm's f32 upcast above the all-reduce (2× wire bytes).
-        y = _barrier(_tag(y, "block_out"))
+        y = jax.lax.optimization_barrier(_tag(y, "block_out"))
         if cfg.post_norm:
             y = _norm_apply(cfg, p["post_attn_norm"], y)
         x = x + y
@@ -312,7 +297,7 @@ def block_apply(
                                seq_len=seq_len)
     else:
         y = mlp_apply(p["mlp"], h, cfg=_mlp_cfg(cfg), compute_dtype=compute_dtype)
-    y = _barrier(_tag(y, "block_out"))
+    y = jax.lax.optimization_barrier(_tag(y, "block_out"))
     if cfg.post_norm:
         y = _norm_apply(cfg, p["post_mlp_norm"], y)
     return x + y, aux, cache
@@ -392,13 +377,13 @@ def block_prefill_paged(
         rope_base=rope_base,
         compute_dtype=compute_dtype,
     )
-    y = _barrier(_tag(y, "block_out"))
+    y = jax.lax.optimization_barrier(_tag(y, "block_out"))
     if cfg.post_norm:
         y = _norm_apply(cfg, p["post_attn_norm"], y)
     x = x + y
     h = _norm_apply(cfg, p["pre_mlp_norm"], x)
     y = mlp_apply(p["mlp"], h, cfg=_mlp_cfg(cfg), compute_dtype=compute_dtype)
-    y = _barrier(_tag(y, "block_out"))
+    y = jax.lax.optimization_barrier(_tag(y, "block_out"))
     if cfg.post_norm:
         y = _norm_apply(cfg, p["post_mlp_norm"], y)
     return x + y, cache

@@ -30,7 +30,7 @@ Serving additions (DESIGN.md §12): ``seq_len`` masks bucketed-prefill
 padding out of capacity competition (mirroring moe.py), and ``dropless``
 sizes both capacities at their worst-case bounds so no assignment can ever
 drop — the row-independence invariant continuous batching needs.  Trace
-under ``with mesh:`` (the engine's ``_with_backend`` enters it).
+under ``jax.set_mesh(mesh)`` (the engine's ``_with_backend`` enters it).
 """
 from __future__ import annotations
 
@@ -39,7 +39,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 
 from repro.models.layers import act_fn
 from repro.models.moe import MoEConfig, _route
@@ -76,7 +75,7 @@ def moe_apply_ep(
     seq_len=None,
     dropless: bool = False,
 ) -> Tuple[jax.Array, Dict]:
-    """x (B,T,D) global → (B,T,D).  Trace under ``with mesh:``.
+    """x (B,T,D) global → (B,T,D).  Trace under ``jax.set_mesh(mesh)``.
 
     ``seq_len`` (traced scalar or None): bucketed-prefill valid length —
     positions >= seq_len are padding and are masked out of routing capacity
@@ -90,7 +89,7 @@ def moe_apply_ep(
         p = unpack_params(p, jnp.float32)
     mesh = current_mesh()
     if mesh is None:
-        raise ValueError("moe_apply_ep must trace under an ambient mesh (`with mesh:`)")
+        raise ValueError("moe_apply_ep must trace under an ambient mesh (jax.set_mesh)")
     ep_axes = tuple(a for a in ep_axes if a in mesh.axis_names)
     assert ep_axes, (mesh.axis_names,)
     # tokens ALWAYS shard over the batch axes (even when 'data' is also an
@@ -230,8 +229,8 @@ def moe_apply_ep(
         aux = {kk: jax.lax.pmean(v, all_axes) for kk, v in aux.items()}
         return y.reshape(Bl, Tl, D), aux["moe_aux_loss"], aux["moe_z_loss"]
 
-    y, aux_l, z_l = shard_map(
-        body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs, check_rep=False
+    y, aux_l, z_l = jax.shard_map(
+        body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs, check_vma=False
     )(
         x,
         jnp.asarray(T if seq_len is None else seq_len, jnp.int32),

@@ -128,10 +128,10 @@ def packed_dense_apply(p, x, *, n_in: int = 1, compute_dtype=None) -> jax.Array:
     """``dense_apply`` for a dict whose 'kernel' is Packed.
 
     Contracts the last ``n_in`` dims of x with the first n_in dims of the
-    (original-shape) kernel.  Packing is along the kernel's LAST axis, so
-    flattening the out dims keeps byte groups aligned with consecutive
-    flattened columns — the packed words reshape straight into the
-    (K, N/per) 2-D kernel layout with no repack.
+    (original-shape) kernel.  The words reshape straight into the kernel's
+    (K, words) 2-D view with no repack; when the out dims are several
+    (q/k/v: (D, H, hd) packed along hd) each hd row is its own packing
+    row, which the kernel wrapper maps back (``row_values``).
     """
     pk: Packed = p["kernel"]
     bias = p.get("bias")
@@ -140,7 +140,7 @@ def packed_dense_apply(p, x, *, n_in: int = 1, compute_dtype=None) -> jax.Array:
 
     backend = resolve_backend()
     f = jnp.asarray(pk.f)
-    if backend in ("unpack", "dense") or f.ndim != 0:
+    if backend in ("unpack", "dense"):
         k = unpack(pk, x.dtype)
         lhs = tuple(range(x.ndim - n_in, x.ndim))
         rhs = tuple(range(n_in))
@@ -148,6 +148,13 @@ def packed_dense_apply(p, x, *, n_in: int = 1, compute_dtype=None) -> jax.Array:
         if bias is not None:
             y = y + bias.astype(y.dtype)
         return y
+    if f.ndim != 0:
+        # one exponent per leading slice belongs to expert stacks
+        # (packed_expert_einsum) or to a scan-stacked group that was not
+        # sliced per layer — neither reaches a plain dense layer.
+        raise ValueError(
+            f"packed dense kernel needs a scalar exponent, got f of shape {f.shape}"
+        )
 
     in_dims = pk.shape[:n_in]
     out_dims = pk.shape[n_in:]
@@ -159,7 +166,7 @@ def packed_dense_apply(p, x, *, n_in: int = 1, compute_dtype=None) -> jax.Array:
     w2 = pk.data.reshape(K, N // per)
     b2 = None if bias is None else bias.reshape(N)
     y = fixedpoint_matmul(
-        x2, w2, f, b2, n_bits=pk.n_bits, n_out=N,
+        x2, w2, f, b2, n_bits=pk.n_bits, n_out=N, row_values=pk.shape[-1],
         interpret=(backend == "interpret"), out_dtype=x.dtype,
     )
     return y.reshape(*lead, *out_dims)
@@ -184,7 +191,8 @@ def packed_expert_einsum(x, pk: Packed, *, compute_dtype=None) -> jax.Array:
 
 def packed_take(pk: Packed, ids, *, dtype=None) -> jax.Array:
     """Embedding lookup from a Packed (vocab, d) table: gather the packed
-    *rows* (bytes pack along d, so a row gather never splits a byte), then
+    *rows* (words pack along d, so a row gather never splits a packing
+    chunk), then
     dequantize only the gathered (..., d/per) words — O(tokens·d) unpack
     work instead of O(vocab·d)."""
     dtype = dtype or jnp.float32

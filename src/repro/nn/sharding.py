@@ -22,25 +22,33 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# jax 0.4.x keeps the ambient mesh in the pjit resource env (entered via
-# ``with mesh:``) — newer jax exposes jax.set_mesh/get_abstract_mesh instead.
-from jax._src.mesh import thread_resources as _thread_resources
+import jax
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.nn.tree import tree_map_with_path
 
 
-def current_mesh() -> Optional[Mesh]:
-    """The ambient physical mesh (``with mesh:``), or None outside one.
+def make_mesh(shape: Sequence[int], names: Sequence[str], devices=None) -> Mesh:
+    """A device mesh whose axes are all ``Auto``: GSPMD propagates shardings
+    from the placed params and the ``with_sharding_constraint`` pins, which
+    is what every sharding rule here assumes (``jax.make_mesh`` alone makes
+    ``Explicit`` axes, whose sharding-in-types rejects that code)."""
+    n = int(np.prod(shape))
+    devices = jax.devices()[:n] if devices is None else devices
+    return jax.make_mesh(tuple(shape), tuple(names), axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
-    Readable mid-trace: the mesh context is a thread-local Python global,
-    not a traced value, so sharded dispatch decisions (moe_ep routing, the
-    paged-attention head-slicing wrapper) can branch on it while jit is
-    tracing — the decision is baked into the trace, which is exactly the
-    engine-pins-at-construction contract DESIGN.md §4 already gives the
+
+def current_mesh():
+    """The ambient mesh (entered with ``jax.set_mesh``), or None outside one.
+
+    Returns the abstract mesh — axis names and sizes, all that the sharded
+    dispatch reads.  Readable mid-trace: sharded dispatch decisions (moe_ep
+    routing, the paged-attention head-slicing wrapper) branch on it while
+    jit is tracing — the decision is baked into the trace, which is exactly
+    the engine-pins-at-construction contract DESIGN.md §4 already gives the
     packed/attention backends."""
-    m = _thread_resources.env.physical_mesh
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
 
 

@@ -9,8 +9,10 @@ kernel timing that the host-side ``StepTracer`` cannot see — the two
 line up via step numbers.
 
 Stop is idempotent: the scheduler calls ``stop()`` both when the window
-elapses and unconditionally in its ``finally`` teardown, and a crashed
-profiler start leaves the window disarmed rather than wedging serving.
+elapses and unconditionally in its ``finally`` teardown.  A requested
+profile that cannot start or stop raises — the window disarms first, so
+the teardown ``stop()`` is a no-op — rather than letting the trace vanish
+from a run that otherwise succeeds.
 """
 from __future__ import annotations
 
@@ -35,13 +37,13 @@ class ProfileWindow:
         if self.done:
             return
         if not self.active:
-            try:
-                import jax
+            import jax
 
+            try:
                 jax.profiler.start_trace(self.log_dir)
-            except Exception:
-                self.done = True  # profiler unavailable: disarm, keep serving
-                return
+            except Exception as e:
+                self.done = True
+                raise RuntimeError(f"profile into {self.log_dir!r} could not start: {e}") from e
             self.active = True
         self.steps_seen += 1
         if self.steps_seen >= self.n_steps:
@@ -53,12 +55,9 @@ class ProfileWindow:
             return
         self.active = False
         self.done = True
-        try:
-            import jax
+        import jax
 
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
+        jax.profiler.stop_trace()
 
 
 def make_profile_window(log_dir: str = "", n_steps: int = 8) -> Optional[ProfileWindow]:

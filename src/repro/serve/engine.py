@@ -35,11 +35,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core.packing import pack_int
 from repro.models.attention import (
     KV_QMAX,
     block_scale_exp,
     cache_read,
-    pack_int4,
+    pool_block,
     quantize_fixed,
 )
 from repro.models.config import ModelConfig
@@ -67,18 +68,14 @@ from repro.nn.sharding import current_mesh, make_rules, mesh_axis_size, sharding
 from repro.nn.tree import tree_bytes
 
 
-def _scatter_blocks(pool, src, bt_row, axis, p_blocks):
-    """Write a batch-of-one prefill cache into the paged pool.
+def _prefill_blocks(pool, src, axis, p_blocks):
+    """A batch-of-one prefill leaf cut into the pool's blocks.
 
-    pool: (n_blocks, block, feat...) — one more leading layer axis when
-    ``axis`` is 1 (scan-stacked group).  src: the prefill leaf, batch axis of
-    size 1 at ``axis`` and a max_len length axis after it.  bt_row
-    (max_blocks,): the slot's PHYSICAL block ids; only the first
-    ``p_blocks`` (the bucket's span — a static per-trace count) are written,
-    and table entries past the allocated prefix are trash (0), so the
-    bucket's padded tail lands in the trash block instead of real capacity.
-    """
-    block = pool.shape[axis + 1]
+    src: the prefill leaf, batch axis of size 1 at ``axis`` and a max_len
+    length axis after it.  Returns (p_blocks, block, *feat) token-major
+    blocks (one more leading layer axis when ``axis`` is 1), padded or cut
+    to the bucket's span."""
+    block = pool_block(pool, axis)
     src = jnp.squeeze(src, axis=axis)  # drop the batch-of-one axis
     need = p_blocks * block
     t = src.shape[axis]
@@ -88,12 +85,30 @@ def _scatter_blocks(pool, src, bt_row, axis, p_blocks):
         src = jnp.pad(src, pad)
     elif need < t:
         src = jax.lax.slice_in_dim(src, 0, need, axis=axis)
-    src = src.reshape(src.shape[:axis] + (p_blocks, block) + src.shape[axis + 1 :])
-    src = src.astype(pool.dtype)
-    ids = bt_row[:p_blocks]
+    return src.reshape(src.shape[:axis] + (p_blocks, block) + src.shape[axis + 1 :])
+
+
+def _set_blocks(pool, blocks, ids, axis):
+    """Write token-major blocks at physical ids, head-major where the pool is."""
+    if pool.ndim - axis == 4:
+        blocks = blocks.swapaxes(axis + 1, axis + 2)
     if axis == 0:
-        return pool.at[ids].set(src)
-    return pool.at[:, ids].set(src)
+        return pool.at[ids].set(blocks)
+    return pool.at[:, ids].set(blocks)
+
+
+def _scatter_blocks(pool, src, bt_row, axis, p_blocks):
+    """Write a batch-of-one prefill cache into the paged pool.
+
+    pool: a paged pool leaf (models.attention.paged_pool_shape) — one more
+    leading layer axis when ``axis`` is 1 (scan-stacked group).  bt_row
+    (max_blocks,): the slot's PHYSICAL block ids; only the first
+    ``p_blocks`` (the bucket's span — a static per-trace count) are written,
+    and table entries past the allocated prefix are trash (0), so the
+    bucket's padded tail lands in the trash block instead of real capacity.
+    """
+    src = _prefill_blocks(pool, src, axis, p_blocks).astype(pool.dtype)
+    return _set_blocks(pool, src, bt_row[:p_blocks], axis)
 
 
 def _scatter_blocks_quant(pool, exp_leaf, src, bt_row, axis, p_blocks):
@@ -102,27 +117,16 @@ def _scatter_blocks_quant(pool, exp_leaf, src, bt_row, axis, p_blocks):
     calibrate each written block's exponent from its FIRST token, quantize
     every token under its block's scale, and scatter the int8 / packed-int4
     mantissas plus the exponent rows."""
-    block = pool.shape[axis + 1]
-    src = cache_read(jnp.squeeze(src, axis=axis), jnp.float32)
-    need = p_blocks * block
-    t = src.shape[axis]
-    if need > t:
-        pad = [(0, 0)] * src.ndim
-        pad[axis] = (0, need - t)
-        src = jnp.pad(src, pad)
-    elif need < t:
-        src = jax.lax.slice_in_dim(src, 0, need, axis=axis)
-    src = src.reshape(src.shape[:axis] + (p_blocks, block) + src.shape[axis + 1 :])
+    src = cache_read(_prefill_blocks(pool, src, axis, p_blocks), jnp.float32)
     bits = 4 if pool.shape[-1] * 2 == src.shape[-1] else 8
     qmax = KV_QMAX[bits]
     e = block_scale_exp(jax.lax.index_in_dim(src, 0, axis + 1, keepdims=False), qmax)
     q = quantize_fixed(src, jnp.expand_dims(e, axis + 1), qmax)
     if bits == 4:
-        q = pack_int4(q)
+        q = pack_int(q, 4)
     ids = bt_row[:p_blocks]
-    if axis == 0:
-        return pool.at[ids].set(q), exp_leaf.at[ids].set(e)
-    return pool.at[:, ids].set(q), exp_leaf.at[:, ids].set(e)
+    exp_leaf = exp_leaf.at[ids].set(e) if axis == 0 else exp_leaf.at[:, ids].set(e)
+    return _set_blocks(pool, q, ids, axis), exp_leaf
 
 
 def filter_logits(logits, temperature, top_k: int):
@@ -336,7 +340,7 @@ class ServeEngine:
     # packed weight words over the nn/sharding logical rules and the paged
     # KV pool over KV heads; None (and a 1-device mesh) serves exactly as
     # before.  Like the kernel backends, the mesh is PINNED at construction
-    # — every jitted trace runs under ``with self.mesh:``.
+    # — every jitted trace runs under ``jax.set_mesh(self.mesh)``.
     mesh: Any = None
     sharding_profile: str = ""  # defaults to cfg.sharding_profile
 
@@ -348,8 +352,8 @@ class ServeEngine:
         # trace from the globals (construct a new engine to switch).
         self.backend = resolve_backend()
         self.attn_backend = resolve_attention_backend()
-        if self.mesh is None:
-            self.mesh = current_mesh()  # constructing under `with mesh:` pins it
+        if self.mesh is None and current_mesh() is not None:
+            self.mesh = jax.sharding.get_mesh()  # constructing under jax.set_mesh pins it
         self.rules = None
         if self.mesh is not None:
             self.rules = make_rules(self.mesh, self.sharding_profile or cfg.sharding_profile)
@@ -462,7 +466,7 @@ class ServeEngine:
                 # the ambient mesh is part of the pinned trace environment:
                 # moe_ep routing and the paged-attention head-slicing
                 # wrapper both branch on current_mesh() while tracing
-                with self.mesh:
+                with jax.set_mesh(self.mesh):
                     return fn(*args)
             return fn(*args)
         finally:

@@ -111,6 +111,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.distributed.fault import StepTimeMonitor
+from repro.models.attention import paged_pool_shape
 from repro.models.lm import PAGED_CACHE_LEAVES, scan_groups
 from repro.obs import NULL_TRACER, MetricsRegistry, RingLog, StatsView, StepTracer, log_buckets
 from repro.obs.profiling import make_profile_window
@@ -512,7 +513,8 @@ class Scheduler:
     def _init_caches(self):
         """Zero cache pool with exactly the prefill trace's leaf dtypes.
         Paged leaves (GroupSpec.paged ∩ PAGED_CACHE_LEAVES) become shared
-        (n_blocks+1, block, ...) pools — +1 for the trash block — replacing
+        pools of n_blocks+1 blocks (``paged_pool_shape``: (n, K, block, hd)
+        head-major KV, (n, block, r) MLA) — +1 for the trash block — replacing
         the per-slot max_len rows entirely; everything else keeps its
         per-row layout with the batch axis widened from 1 to n_slots.
 
@@ -532,18 +534,18 @@ class Scheduler:
                 sub = {}
                 for name, sd in shapes[g.name][f"sub{j}"].items():
                     if g.paged[j] and name in PAGED_CACHE_LEAVES:
-                        feat = sd.shape[axis + 2 :]
+                        lead, feat = sd.shape[:axis], sd.shape[axis + 2 :]
                         if qbits:
                             if qbits == 4:
                                 feat = feat[:-1] + (feat[-1] // 2,)
                             sub[name] = jnp.zeros(
-                                sd.shape[:axis] + (n_phys, blk) + feat, jnp.int8
+                                paged_pool_shape(lead, n_phys, blk, feat), jnp.int8
                             )
                             sub[name + "_scale"] = jnp.zeros(
-                                sd.shape[:axis] + (n_phys,) + feat[:-1], jnp.int32
+                                lead + (n_phys,) + feat[:-1], jnp.int32
                             )
                             continue
-                        shape = sd.shape[:axis] + (n_phys, blk) + feat
+                        shape = paged_pool_shape(lead, n_phys, blk, feat)
                     else:
                         shape = sd.shape[:axis] + (S,) + sd.shape[axis + 1 :]
                     sub[name] = jnp.zeros(shape, sd.dtype)

@@ -36,12 +36,12 @@ from repro.nn.sharding import ShardingRules
 def pool_head_shards(rules: ShardingRules, shape: Sequence[int], axis: int) -> int:
     """How many ways a paged data-pool leaf's KV-head axis shards under
     ``rules`` (1 = replicated).  ``shape`` is the pool leaf shape —
-    ``(n_blocks, block, K, hd)`` at ``axis``=0, one leading layer dim at
-    ``axis``=1; MLA rank-space leaves carry a single feature dim and never
-    shard.  Applies the same divisibility fallback as the param rules."""
-    feat = shape[axis + 2 :]
-    if len(feat) != 2:
-        return 1  # MLA c_kv/k_rope: (r,) — no KV-head axis
+    head-major ``(n_blocks, K, block, hd)`` at ``axis``=0, one leading
+    layer dim at ``axis``=1; MLA rank-space leaves ``(n_blocks, block, r)``
+    have no KV-head axis and never shard.  Applies the same divisibility
+    fallback as the param rules."""
+    if len(shape) - axis != 4:
+        return 1  # MLA c_kv/k_rope: no KV-head axis
     mapped = rules.axis_map.get("kv_heads")
     if mapped is None:
         return 1
@@ -49,7 +49,7 @@ def pool_head_shards(rules: ShardingRules, shape: Sequence[int], axis: int) -> i
     size = 1
     for a in axes:
         size *= rules.mesh.shape[a]
-    return size if size > 1 and feat[0] % size == 0 else 1
+    return size if size > 1 and shape[axis + 1] % size == 0 else 1
 
 
 def pool_pspec(rules: ShardingRules, shape: Sequence[int], axis: int) -> P:
@@ -59,7 +59,7 @@ def pool_pspec(rules: ShardingRules, shape: Sequence[int], axis: int) -> P:
         return P()
     mapped = rules.axis_map["kv_heads"]
     spec = [None] * len(shape)
-    spec[axis + 2] = mapped if isinstance(mapped, str) else tuple(mapped)
+    spec[axis + 1] = mapped if isinstance(mapped, str) else tuple(mapped)
     return P(*spec)
 
 
@@ -83,6 +83,7 @@ def pool_bytes_per_device(
     this to price an 8-way pool without owning 8 devices."""
     import numpy as np
 
+    from repro.models.attention import paged_pool_shape
     from repro.models.lm import PAGED_CACHE_LEAVES, scan_groups
 
     shapes = engine.prefill_cache_shapes()
@@ -95,15 +96,15 @@ def pool_bytes_per_device(
             for name, sd in shapes[g.name][f"sub{j}"].items():
                 if not (g.paged[j] and name in PAGED_CACHE_LEAVES):
                     continue
-                feat = sd.shape[axis + 2 :]
+                lead, feat = sd.shape[:axis], sd.shape[axis + 2 :]
                 if qbits and len(feat):
                     if qbits == 4:
                         feat = feat[:-1] + (feat[-1] // 2,)
-                    shape = sd.shape[:axis] + (n_phys, block_size) + feat
+                    shape = paged_pool_shape(lead, n_phys, block_size, feat)
                     data_b = int(np.prod(shape))  # int8 words
-                    scale_b = int(np.prod(sd.shape[:axis] + (n_phys,) + feat[:-1])) * 4
+                    scale_b = int(np.prod(lead + (n_phys,) + feat[:-1])) * 4
                 else:
-                    shape = sd.shape[:axis] + (n_phys, block_size) + feat
+                    shape = paged_pool_shape(lead, n_phys, block_size, feat)
                     data_b = int(np.prod(shape)) * sd.dtype.itemsize
                     scale_b = 0
                 if model_shards:

@@ -17,7 +17,7 @@ def test_symog_update_matches_oracle(rng, shape, n_bits):
     g = jax.random.normal(k2, shape) * 0.05
     v = jax.random.normal(k3, shape) * 0.01
     kw = dict(delta=0.25, lam_eff=0.7, lr=0.01, mu=0.9, n_bits=n_bits)
-    w1, v1 = symog_update(w, g, v, **kw)
+    w1, v1 = symog_update(w, g, v, **kw, interpret=True)
     w2, v2 = symog_update_ref(w, g, v, **kw)
     np.testing.assert_allclose(w1, w2, rtol=1e-6, atol=1e-7)
     np.testing.assert_allclose(v1, v2, rtol=1e-6, atol=1e-7)
@@ -31,7 +31,8 @@ def test_symog_update_traced_scalars(rng):
 
     @jax.jit
     def step(w, g, v, lam):
-        return symog_update(w, g, v, delta=0.5, lam_eff=lam, lr=0.1, mu=0.9, n_bits=2)
+        return symog_update(w, g, v, delta=0.5, lam_eff=lam, lr=0.1, mu=0.9, n_bits=2,
+                            interpret=True)
 
     w1, _ = step(w, g, v, jnp.float32(2.0))
     w2, _ = symog_update_ref(w, g, v, delta=0.5, lam_eff=2.0, lr=0.1, mu=0.9, n_bits=2)
@@ -48,7 +49,8 @@ def test_symog_update_equals_paper_semantics(rng):
     f, delta = core.optimal_f(w, 2)
     lam, lr, mu = 3.0, 0.02, 0.9
     lam_eff = lam * 2.0 / w.size
-    w_k, v_k = symog_update(w, g, v, delta=delta, lam_eff=lam_eff, lr=lr, mu=mu, n_bits=2)
+    w_k, v_k = symog_update(w, g, v, delta=delta, lam_eff=lam_eff, lr=lr, mu=mu, n_bits=2,
+                            interpret=True)
     # reference composition: reg grad → momentum → nesterov → clip
     g_tot = g + lam * core.layer_reg_grad(w, delta, 2)
     v_ref = mu * v + g_tot
@@ -66,7 +68,7 @@ def test_fixedpoint_matmul_matches_oracle(rng, mkn, n_bits, f):
     w = jax.random.normal(k1, (K, N)) * 0.2
     x = jax.random.normal(k2, (M, K))
     pw = pack_weight(w, f, n_bits)
-    y = fixedpoint_matmul(x, pw, f, n_bits=n_bits, n_out=N)
+    y = fixedpoint_matmul(x, pw, f, n_bits=n_bits, n_out=N, interpret=True)
     y_ref = fixedpoint_matmul_ref(x, pw, f, n_bits=n_bits, n_out=N)
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
 
@@ -76,7 +78,7 @@ def test_fixedpoint_matmul_batched_input(rng):
     w = jax.random.normal(rng, (32, 48)) * 0.3
     x = jax.random.normal(jax.random.fold_in(rng, 1), (2, 3, 32))
     pw = pack_weight(w, 2, 2)
-    y = fixedpoint_matmul(x, pw, 2, n_bits=2, n_out=48)
+    y = fixedpoint_matmul(x, pw, 2, n_bits=2, n_out=48, interpret=True)
     assert y.shape == (2, 3, 48)
     y_ref = fixedpoint_matmul_ref(x.reshape(-1, 32), pw, 2, n_bits=2, n_out=48)
     np.testing.assert_allclose(y.reshape(-1, 48), y_ref, rtol=1e-5, atol=1e-5)
@@ -93,5 +95,5 @@ def test_fixedpoint_matmul_equals_float_quantized_matmul(rng):
     qw = core.quantize(w, core.delta_from_f(f), 2)
     y_float = x @ qw
     pw = pack_weight(w, f, 2)
-    y_packed = fixedpoint_matmul(x, pw, f, n_bits=2, n_out=64)
+    y_packed = fixedpoint_matmul(x, pw, f, n_bits=2, n_out=64, interpret=True)
     np.testing.assert_allclose(y_packed, y_float, rtol=1e-5, atol=1e-5)

@@ -6,8 +6,8 @@ Two layers of contract:
     ``quantize_fixed``) bounds its round-trip error by the grid step the
     calibration picked — a hypothesis sweep drives adversarial per-head
     dynamic ranges (heads 2^10 apart in the same block) through int8 AND
-    packed int4, and ``pack_int4``/``unpack_int4`` round-trip every nibble
-    exactly;
+    packed int4, and 4-bit ``pack_int``/``unpack_int`` round-trip every
+    nibble exactly;
   - serving: on a quantized pool the write-once-read-many discipline makes
     the pool its own oracle — prefix-cache hit vs miss, chunked vs one-shot
     prefill, and serve-twice replays are all BIT-identical streams, because
@@ -23,13 +23,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
-from repro.kernels.paged_attention.ref import unpack_int4
+from repro.core.packing import pack_int, unpack_int
 from repro.models.attention import (
     KV_EXP_MAX,
     KV_EXP_MIN,
     KV_QMAX,
     block_scale_exp,
-    pack_int4,
     quantize_fixed,
 )
 from repro.models.lm import init_lm
@@ -65,9 +64,9 @@ def test_pack_unpack_int4_exact_round_trip():
     vals = jnp.arange(-8, 8, dtype=jnp.int32)
     lo, hi = jnp.meshgrid(vals, vals, indexing="ij")
     x = jnp.stack([lo.ravel(), hi.ravel()], axis=-1)  # (256, 2): w = 1
-    packed = pack_int4(x)
+    packed = pack_int(x, 4)
     assert packed.dtype == jnp.int8 and packed.shape == (256, 1)
-    np.testing.assert_array_equal(np.asarray(unpack_int4(packed)), np.asarray(x))
+    np.testing.assert_array_equal(np.asarray(unpack_int(packed, 4, 2)), np.asarray(x))
 
 
 try:
@@ -104,7 +103,7 @@ def test_block_quantize_round_trip_bound(bits, spread, seed):
     assert bool(jnp.all((e >= KV_EXP_MIN) & (e <= KV_EXP_MAX)))
     q = quantize_fixed(pool, e[:, None], qmax)
     if bits == 4:
-        q = unpack_int4(pack_int4(q))  # the pool stores packed words
+        q = unpack_int(pack_int(q, 4), 4, q.shape[-1])  # the pool stores packed words
     deq = q.astype(jnp.float32) * jnp.exp2(e[:, None].astype(jnp.float32))[..., None]
     err = np.abs(np.asarray(deq) - np.asarray(pool))
     step = np.broadcast_to(  # Δ = 2^e, broadcast over (block, pos, head, lane)

@@ -12,15 +12,16 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.models.moe import MoEConfig, moe_init, moe_apply_dense_ref, moe_apply
     from repro.models.moe_ep import moe_apply_ep
+    from repro.nn.sharding import make_mesh
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     cfg = MoEConfig(d_model=16, n_experts=8, top_k=2, d_ff_expert=8,
                     n_shared_experts=1, capacity_factor=8.0)
     key = jax.random.PRNGKey(0)
     p = moe_init(key, cfg)
     x = jax.random.normal(jax.random.fold_in(key, 1), (4, 6, 16)) * 0.5
 
-    with mesh:
+    with jax.set_mesh(mesh):
         x_sh = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
         p_sh = jax.device_put(p, NamedSharding(mesh, P()))
         # expert leaves sharded over model
@@ -56,11 +57,10 @@ _SCRIPT = textwrap.dedent("""
 
 
 def test_moe_ep_matches_dense_ref():
-    # fixed with the mesh-aware serving PR: the EP dispatch was written
-    # against a newer jax API surface (jax.set_mesh/jax.shard_map) and the
-    # capacity numbering let non-owned assignment partitions consume send
-    # slots; ported to the `with mesh:` context + masked slot numbering,
-    # the 1-D/2-D EP output now matches the dense reference within 2e-4.
+    # the EP dispatch traces under jax.set_mesh over an all-Auto mesh
+    # (repro.nn.sharding.make_mesh) with masked slot numbering, so
+    # non-owned assignment partitions never consume send slots; the 1-D/2-D
+    # EP output matches the dense reference within 2e-4.
     root = os.path.join(os.path.dirname(__file__), "..")
     r = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,

@@ -236,8 +236,10 @@ def test_profile_window_disarms_on_start_failure(monkeypatch):
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
     win = ProfileWindow("/tmp/prof", n_steps=2)
-    win.on_step()
-    assert win.done and not win.active  # disarmed, serving continues
+    with pytest.raises(RuntimeError, match="could not start"):
+        win.on_step()  # a requested profile never vanishes silently
+    assert win.done and not win.active  # disarmed: teardown stop() is a no-op
+    win.stop()
     with pytest.raises(ValueError):
         ProfileWindow("/tmp/prof", n_steps=0)
 

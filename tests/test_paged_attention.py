@@ -3,7 +3,7 @@
 The serving hot path replaces gather → mask → softmax with a Pallas kernel
 whose block-table lookup lives inside the online-softmax loop (DESIGN.md
 §9).  These tests run the kernel in interpret mode (no TPU) and pin it,
-layer by layer, to the composed REFERENCE path (``paged_gather`` + dense
+layer by layer, to the composed REFERENCE path (``gather_logical`` + dense
 masked softmax) it fuses away:
 
   - kernel vs pure-jnp oracle across block sizes {8, 16}, GQA/MQA head
@@ -13,8 +13,9 @@ masked softmax) it fuses away:
   - the real layer entry points (attn_decode / attn_verify_paged /
     attn_prefill_paged / mla_decode / mla_verify_paged) under the
     'fused-interpret' backend vs 'composed' — same params, same pools;
-  - a hypothesis property test that targets the ``paged_gather`` reference
-    EXPLICITLY (any table permutation gathers exactly the rows it names);
+  - a hypothesis property test that targets the ``gather_logical``
+    reference EXPLICITLY (any table permutation gathers exactly the rows it
+    names, from MLA and head-major KV pools);
   - end-to-end: greedy serve() over the fused backend is token-identical
     to ``generate_static`` (which always runs the dense uniform-pos path).
 """
@@ -24,6 +25,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.core.packing import pack_int
 from repro.kernels.dispatch import set_attention_backend
 from repro.kernels.paged_attention import paged_attention, paged_attention_mla
 from repro.kernels.paged_attention.ref import (
@@ -45,8 +47,6 @@ from repro.models.attention import (
     mla_decode,
     mla_init,
     mla_verify_paged,
-    pack_int4,
-    paged_gather,
     quantize_fixed,
 )
 
@@ -57,9 +57,10 @@ def _quant_pool(pool, bits):
     """Per-block SYMOG quantization of a float pool, first-position
     calibrated — exactly the serving write path's arithmetic (§11)."""
     qmax = KV_QMAX[bits]
-    e = block_scale_exp(pool[:, 0], qmax)  # (n_blocks[, K])
-    q = quantize_fixed(pool, e[:, None], qmax)
-    return (pack_int4(q) if bits == 4 else q), e
+    first = pool[:, :, 0] if pool.ndim == 4 else pool[:, 0]  # head-major KV
+    e = block_scale_exp(first, qmax)  # (n_blocks[, K])
+    q = quantize_fixed(pool, e[..., None], qmax)
+    return (pack_int(q, 4) if bits == 4 else q), e
 
 
 @pytest.fixture
@@ -83,8 +84,8 @@ def _case(key, *, B, T, K, G, hd, block, max_blocks, int8=False, dtype=jnp.float
     n_blocks = B * max_blocks + 1
     ks = jax.random.split(key, 6)
     q = jax.random.normal(ks[0], (B, T, K, G, hd), jnp.float32).astype(dtype)
-    k_pool = jax.random.normal(ks[1], (n_blocks, block, K, hd), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (n_blocks, block, K, hd), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (n_blocks, K, block, hd), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (n_blocks, K, block, hd), jnp.float32)
     bt = _tables(ks[3], B, max_blocks, n_blocks)
     pos_last = jax.random.randint(ks[4], (B,), T - 1, max_blocks * block)
     pos0 = (pos_last - (T - 1)).astype(jnp.int32)
@@ -251,11 +252,11 @@ def _layer_case(key, cfg, *, B, max_blocks, block, int8=False):
     pool_dtype = jnp.int8 if int8 else jnp.float32
     cache = {
         "k": cache_write(
-            jax.random.normal(ks[1], (n_blocks, block, K, hd), jnp.float32) * 0.5,
+            jax.random.normal(ks[1], (n_blocks, K, block, hd), jnp.float32) * 0.5,
             pool_dtype,
         ),
         "v": cache_write(
-            jax.random.normal(ks[2], (n_blocks, block, K, hd), jnp.float32) * 0.5,
+            jax.random.normal(ks[2], (n_blocks, K, block, hd), jnp.float32) * 0.5,
             pool_dtype,
         ),
     }
@@ -474,7 +475,7 @@ def test_mla_verify_layer_parity(rng, fused_interpret):
 
 
 # ---------------------------------------------------------------------------
-# property test: the paged_gather REFERENCE itself (the oracle the kernel is
+# property test: the gather_logical REFERENCE itself (the oracle the kernel is
 # pinned to) — any table gathers exactly the physical rows it names.
 # Guarded like test_blockpool.py so minimal installs still run the rest.
 # ---------------------------------------------------------------------------
@@ -498,24 +499,26 @@ except ImportError:  # pragma: no cover - exercised on minimal installs only
 
 @_hyp
 def test_paged_gather_reference_property(B, max_blocks, block, seed):
-    """paged_gather (models) and gather_logical (kernel oracle) agree, and
-    entry (b, j*block + t) is EXACTLY pool[tables[b, j], t] — with repeated
-    and trash blocks allowed, as the scheduler's tables produce them."""
+    """gather_logical's entry (b, j*block + t) is EXACTLY token t of
+    physical block tables[b, j] — for an MLA (n, block, r) pool and a
+    head-major KV (n, K, block, hd) pool — with repeated and trash blocks
+    allowed, as the scheduler's tables produce them."""
     n_blocks = max_blocks * B + 1
     key = jax.random.PRNGKey(seed)
-    pool = jax.random.normal(
-        jax.random.fold_in(key, 0), (n_blocks, block, 3), jnp.float32
-    )
     bt = jax.random.randint(jax.random.fold_in(key, 1), (B, max_blocks), 0, n_blocks)
-    got = np.asarray(paged_gather(pool, bt.astype(jnp.int32)))
-    np.testing.assert_array_equal(got, np.asarray(gather_logical(pool, bt)))
-    pool_np, bt_np = np.asarray(pool), np.asarray(bt)
-    assert got.shape == (B, max_blocks * block, 3)
-    for b in range(B):
-        for j in range(max_blocks):
-            np.testing.assert_array_equal(
-                got[b, j * block : (j + 1) * block], pool_np[bt_np[b, j]]
-            )
+    bt_np = np.asarray(bt)
+    for shape in ((n_blocks, block, 3), (n_blocks, 2, block, 3)):
+        pool = jax.random.normal(jax.random.fold_in(key, 0), shape, jnp.float32)
+        got = np.asarray(gather_logical(pool, bt.astype(jnp.int32)))
+        tokens = np.asarray(pool)
+        if len(shape) == 4:  # token-major view of the head-major blocks
+            tokens = tokens.swapaxes(1, 2)
+        assert got.shape == (B, max_blocks * block) + tokens.shape[2:]
+        for b in range(B):
+            for j in range(max_blocks):
+                np.testing.assert_array_equal(
+                    got[b, j * block : (j + 1) * block], tokens[bt_np[b, j]]
+                )
 
 
 # ---------------------------------------------------------------------------

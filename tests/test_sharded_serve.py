@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro import configs, core
 from repro.models import init_lm, set_packed_backend
+from repro.nn.sharding import make_mesh
 from repro.serve import Request, ServeConfig, ServeEngine
 
 pytestmark = [
@@ -74,7 +75,7 @@ def test_sharded_serve_token_identical(arch):
     cfg = configs.get_reduced(arch)
     qt, pt = _trees(cfg)
     ref = _tokens(ServeEngine(cfg, qt, max_len=MAX_LEN, compute_dtype=jnp.float32), cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     eng = ServeEngine(cfg, qt, max_len=MAX_LEN, compute_dtype=jnp.float32, mesh=mesh)
     assert eng.rules is not None and eng.model_shards() == 4
     assert _tokens(eng, cfg) == ref
@@ -87,10 +88,10 @@ def test_sharded_serve_token_identical(arch):
 def test_engine_pins_ambient_mesh_at_construction():
     cfg = configs.get_reduced("internlm2-1.8b")
     qt, _ = _trees(cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
-    with mesh:
+    mesh = make_mesh((2, 4), ("data", "model"))
+    with jax.set_mesh(mesh):
         eng = ServeEngine(cfg, qt, max_len=MAX_LEN, compute_dtype=jnp.float32)
-    assert eng.mesh is mesh  # `with mesh:` construction pins, like backends
+    assert eng.mesh is mesh  # jax.set_mesh construction pins, like backends
 
 
 # ---------------------------------------------------------------------------
@@ -106,7 +107,7 @@ def test_quantized_pool_shards_kv_heads(dtype):
     ref = _tokens(ref_eng, cfg)
 
     # 2 model shards divide the 2 KV heads; 4 would not (replication fallback)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     eng = ServeEngine(cfg, params, max_len=MAX_LEN, compute_dtype=jnp.float32, mesh=mesh)
     config = ServeConfig(n_slots=2, temperature=0.0)
     comps, sched = eng.serve(_requests(cfg.vocab_size), config, return_scheduler=True)
@@ -120,12 +121,12 @@ def test_quantized_pool_shards_kv_heads(dtype):
                 spec = leaf.sharding.spec
                 if g.paged[j] and name in PAGED_CACHE_LEAVES:
                     n_data += 1
-                    head_dim_spec = spec[axis + 2] if len(spec) > axis + 2 else None
-                    if head_dim_spec == "model":
+                    head_spec = spec[axis + 1] if len(spec) > axis + 1 else None
+                    if head_spec == "model":
                         n_sharded += 1
                         # per-device slice holds K/m heads of every block
                         local = leaf.addressable_shards[0].data.shape
-                        assert local[axis + 2] * 2 == leaf.shape[axis + 2]
+                        assert local[axis + 1] * 2 == leaf.shape[axis + 1]
                 else:
                     n_scale += 1
                     # scale exponents are allocated replicated; after a
@@ -144,14 +145,14 @@ def test_pool_replication_fallback_when_heads_do_not_divide():
     from repro.nn.sharding import make_rules
     from repro.serve.sharding import pool_head_shards, pool_pspec
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_rules(mesh, "dp_tp")
-    assert pool_head_shards(rules, (9, 16, 2, 8), 0) == 1  # 2 heads, 4 shards
-    assert pool_head_shards(rules, (9, 16, 4, 8), 0) == 4
-    assert pool_head_shards(rules, (3, 9, 16, 4, 8), 1) == 4  # stacked
+    assert pool_head_shards(rules, (9, 2, 16, 8), 0) == 1  # 2 heads, 4 shards
+    assert pool_head_shards(rules, (9, 4, 16, 8), 0) == 4
+    assert pool_head_shards(rules, (3, 9, 4, 16, 8), 1) == 4  # stacked
     assert pool_head_shards(rules, (9, 16, 7), 0) == 1  # MLA rank-space leaf
-    assert tuple(pool_pspec(rules, (9, 16, 4, 8), 0)) == (None, None, "model", None)
-    assert tuple(pool_pspec(rules, (9, 16, 2, 8), 0)) == ()
+    assert tuple(pool_pspec(rules, (9, 4, 16, 8), 0)) == (None, "model", None, None)
+    assert tuple(pool_pspec(rules, (9, 2, 16, 8), 0)) == ()
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +166,7 @@ def test_ep_moe_decodes_under_scheduler(arch):
     assert not ref_eng.capabilities()["ep_moe"]  # off-mesh: dispatch fallback
     ref = _tokens(ref_eng, cfg)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     eng = ServeEngine(cfg, params, max_len=MAX_LEN, compute_dtype=jnp.float32, mesh=mesh)
     cap = eng.capabilities()["ep_moe"]
     assert bool(cap), cap.reason
