@@ -33,7 +33,10 @@ Telemetry (DESIGN.md §13): the stats report is the scheduler's metrics-
 registry snapshot; ``--metrics-json PATH`` writes it as JSON,
 ``--trace-out PATH`` turns on step-span tracing and exports a Chrome
 ``trace_event`` file for Perfetto, and ``--profile-dir PATH`` wraps the
-first ``--profile-steps`` serve steps in a ``jax.profiler`` capture.
+first ``--profile-steps`` serve steps in a ``jax.profiler`` capture.  Either
+flag turns the span tracer on, so the capture carries the scheduler's spans
+(step, grow, admit, chunk, decode, emit) as host events on the profiler's
+own clock, beside the device's operations.
 """
 from __future__ import annotations
 
@@ -292,7 +295,8 @@ def main() -> None:
                          "drop first; also bounds the scheduler event logs)")
     ap.add_argument("--profile-dir", default="",
                     help="--continuous: capture a jax.profiler trace of the "
-                         "first --profile-steps serve steps into this dir "
+                         "first --profile-steps serve steps into this dir, "
+                         "with the scheduler's spans as host events "
                          "(open with TensorBoard or Perfetto)")
     ap.add_argument("--profile-steps", type=int, default=8,
                     help="--profile-dir: serve steps inside the capture window")
@@ -360,7 +364,7 @@ def main() -> None:
             dcfg = core.SymogConfig(n_bits=args.draft_bits, total_steps=1)
             draft = core.pack_tree(params, core.symog_init(params, dcfg), dcfg)
             spec = SpeculativeConfig(draft=draft, k=args.draft_k)
-        tele = TelemetryConfig(trace=bool(args.trace_out),
+        tele = TelemetryConfig(trace=bool(args.trace_out or args.profile_dir),
                                trace_capacity=args.trace_capacity,
                                profile_dir=args.profile_dir,
                                profile_steps=args.profile_steps)

@@ -9,7 +9,7 @@ from .metrics import (
     log_buckets,
 )
 from .profiling import ProfileWindow, make_profile_window
-from .tracing import NULL_TRACER, RingLog, StepTracer
+from .tracing import NULL_SPAN, NULL_TRACER, RingLog, StepTracer
 
 __all__ = [
     "RingLog",
@@ -19,6 +19,7 @@ __all__ = [
     "MetricsRegistry",
     "StatsView",
     "log_buckets",
+    "NULL_SPAN",
     "NULL_TRACER",
     "StepTracer",
     "ProfileWindow",

@@ -5,8 +5,12 @@
 first decode step after arming starts a ``jax.profiler`` trace, and the
 window stops it after N steps (or at serve teardown, whichever comes
 first).  The resulting TensorBoard-loadable trace shows device-side
-kernel timing that the host-side ``StepTracer`` cannot see — the two
-line up via step numbers.
+kernel timing that the host-side ``StepTracer`` cannot see.  With span
+tracing on (``TelemetryConfig.trace``; the launcher turns it on with
+``--profile-dir``), every span of the ``StepTracer`` is also a profiler
+annotation, so the program's spans (step, grow, admit, chunk, decode,
+verify, emit) appear in the same trace as host events on the profiler's
+own clock, each ``step`` marked as a ``serve_step`` with its number.
 
 Stop is idempotent: the scheduler calls ``stop()`` both when the window
 elapses and unconditionally in its ``finally`` teardown.  A requested
@@ -21,7 +25,8 @@ from typing import Optional
 
 class ProfileWindow:
     """Capture ``n_steps`` serve steps into a jax.profiler trace under
-    ``log_dir``.  Inert when ``log_dir`` is empty."""
+    ``log_dir``, the scheduler's spans included when its tracer is on.
+    Inert when ``log_dir`` is empty."""
 
     def __init__(self, log_dir: str = "", n_steps: int = 8):
         if n_steps < 1:

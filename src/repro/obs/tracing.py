@@ -1,18 +1,26 @@
 """Step-span tracing: ring-buffered span records with Chrome
 ``trace_event`` export (DESIGN.md §13).
 
-The scheduler wraps each phase of a serve step (admit, chunk, decode,
-verify) in a span and marks point events (preempt, evict, COW, prefix
-hit, cancel) as instants.  Records live in a ``deque(maxlen=capacity)``
-ring — on a long-running serve the OLDEST spans are dropped first, so
-the trace is always the most recent window of ``capacity`` records and
-memory is bounded regardless of uptime (same drop semantics as the
-scheduler's ``events`` / ``admit_times`` logs, which share this
+The scheduler wraps each serve step in a ``step`` span and each phase
+of it (grow, admit, chunk, decode, verify, emit) in a child span that
+carries the same ``step`` number, and marks point events (preempt,
+evict, COW, prefix hit, cancel) as instants.  Records live in a
+``deque(maxlen=capacity)`` ring — on a long-running serve the OLDEST
+spans are dropped first, so the trace is always the most recent window
+of ``capacity`` records and memory is bounded regardless of uptime (same
+drop semantics as the scheduler's ``events`` log, which shares this
 capacity knob).
 
-Tracing is OFF by default: the scheduler holds ``NULL_TRACER``, whose
-methods are no-ops, so the untraced hot path pays one attribute call
-per phase.  ``StepTracer.export_chrome()`` emits the Chrome
+Every span also opens a ``jax.profiler.TraceAnnotation`` named by its
+kind, and a ``step`` span a ``StepTraceAnnotation("serve_step",
+step_num=...)`` around it, so a ``jax.profiler`` trace taken while the
+tracer is on carries the program's spans as host events on the device
+trace's own clock.
+
+Tracing is OFF by default: the scheduler holds ``NULL_TRACER``, which
+records nothing and opens no annotation; call sites build a span (and
+compute its arguments) only when ``tracer.enabled``, and otherwise enter
+``NULL_SPAN``.  ``StepTracer.export_chrome()`` emits the Chrome
 ``trace_event`` JSON format — complete duration events (``ph="X"``,
 microsecond ``ts``/``dur``) plus instants (``ph="i"``) in a
 ``{"traceEvents": [...]}`` document that chrome://tracing and Perfetto
@@ -24,6 +32,8 @@ import json
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
+
+from jax import profiler
 
 # Span/instant kinds -> stable Chrome-trace track ids (tid).  One track
 # per kind keeps Perfetto rows readable; unknown kinds land on track 0.
@@ -38,14 +48,16 @@ TRACK_IDS: Dict[str, int] = {
     "evict": 7,
     "prefix_hit": 8,
     "cancel": 9,
+    "grow": 10,
+    "emit": 11,
 }
 
 
 class RingLog(list):
     """A list whose ``append`` drops the OLDEST entry once ``capacity`` is
-    reached — the bound behind ``Scheduler.events`` and
-    ``Scheduler.admit_times`` (same capacity knob as the span ring, same
-    drop semantics: the log is always the most recent ``capacity`` records;
+    reached — the bound behind ``Scheduler.events`` (same capacity knob
+    as the span ring, same drop semantics: the log is always the most
+    recent ``capacity`` records;
     ``dropped`` counts what aged out).  A list subclass, not a deque, so
     existing consumers keep slicing (``log[1:]``) and indexing."""
 
@@ -64,22 +76,38 @@ class RingLog(list):
 
 
 class _Span:
-    """Context manager handed out by ``StepTracer.span``; records on exit."""
+    """Context manager handed out by ``StepTracer.span``; records on exit.
+    Its profiler annotations open before its clock starts and close after
+    it stops, so the host event in a profiler trace encloses the record.
+    An annotation's clock starts when it is created, so they are created
+    outermost first, just before the span's own clock starts."""
 
-    __slots__ = ("_tracer", "kind", "args", "_t0")
+    __slots__ = ("_tracer", "kind", "args", "_t0", "_annotations")
 
     def __init__(self, tracer: "StepTracer", kind: str, args: Dict[str, object]):
         self._tracer = tracer
         self.kind = kind
         self.args = args
         self._t0 = 0.0
+        self._annotations: Tuple[profiler.TraceAnnotation, ...] = ()
 
     def __enter__(self) -> "_Span":
+        if self.kind == "step":
+            self._annotations = (
+                profiler.StepTraceAnnotation("serve_step", step_num=self.args["step"]),
+                profiler.TraceAnnotation("step"),
+            )
+        else:
+            self._annotations = (profiler.TraceAnnotation(self.kind),)
+        for a in self._annotations:
+            a.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
+        for a in reversed(self._annotations):
+            a.__exit__(*exc)
         self._tracer._n_spans += 1
         self._tracer._records.append((self.kind, self._t0, t1 - self._t0, self.args))
 
@@ -99,7 +127,7 @@ class _NullSpan:
     args: Dict[str, object] = {}
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 class StepTracer:
@@ -195,7 +223,7 @@ class StepTracer:
 
 class _NullTracer(StepTracer):
     """No-op tracer held by un-instrumented schedulers: every record path
-    short-circuits, so tracing off costs one method call per phase."""
+    short-circuits and no profiler annotation opens."""
 
     enabled = False
 
@@ -203,7 +231,7 @@ class _NullTracer(StepTracer):
         super().__init__(capacity=1)
 
     def span(self, kind: str, **args: object) -> _NullSpan:  # type: ignore[override]
-        return _NULL_SPAN
+        return NULL_SPAN
 
     def instant(self, kind: str, **args: object) -> None:
         pass

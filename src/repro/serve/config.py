@@ -34,15 +34,18 @@ class TelemetryConfig:
     the optional layers:
 
     trace           — record step spans and instants into the ring
-                      tracer (off: the scheduler holds ``NULL_TRACER``);
+                      tracer, each span also a ``jax.profiler``
+                      annotation (off: the scheduler holds
+                      ``NULL_TRACER``);
     trace_capacity  — ring capacity shared by the tracer AND the
-                      scheduler's ``events`` / ``admit_times`` logs:
-                      all three keep the most recent ``trace_capacity``
-                      records and silently drop the oldest beyond that,
-                      bounding memory on long-running serves;
+                      scheduler's ``events`` log: both keep the most
+                      recent ``trace_capacity`` records and silently drop
+                      the oldest beyond that, bounding memory on
+                      long-running serves;
     profile_dir     — non-empty arms a ``jax.profiler`` capture window
                       (TensorBoard trace) over the first
-                      ``profile_steps`` serve steps;
+                      ``profile_steps`` serve steps; with ``trace`` on,
+                      the program's spans appear in it as host events;
     profile_steps   — capture-window length in serve steps;
     straggler_warn  — warn once (one line on stderr) when the step-time
                       monitor's straggler fraction exceeds this after
@@ -93,8 +96,6 @@ class ServeConfig:
                      committed (per-request overrides via
                      ``Scheduler.submit``); replays after preemption are
                      deduplicated, so every token streams exactly once;
-    time_admissions — record per-admission wall times
-                     (``Scheduler.admit_times``);
     telemetry      — observability knobs (``TelemetryConfig``): span
                      tracing, ring capacities, profiler window,
                      straggler warning (DESIGN.md §13).
@@ -110,7 +111,6 @@ class ServeConfig:
     speculative: Optional[Any] = None  # serve.SpeculativeConfig
     prefill_chunk: int = 0
     on_token: Optional[Callable[[int, int], None]] = None
-    time_admissions: bool = False
     telemetry: TelemetryConfig = TelemetryConfig()
 
     def __post_init__(self):

@@ -97,6 +97,7 @@ needs for EOS and budget bookkeeping.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import sys
 import time
@@ -113,7 +114,15 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.distributed.fault import StepTimeMonitor
 from repro.models.attention import paged_pool_shape
 from repro.models.lm import PAGED_CACHE_LEAVES, scan_groups
-from repro.obs import NULL_TRACER, MetricsRegistry, RingLog, StatsView, StepTracer, log_buckets
+from repro.obs import (
+    NULL_SPAN,
+    NULL_TRACER,
+    MetricsRegistry,
+    RingLog,
+    StatsView,
+    StepTracer,
+    log_buckets,
+)
 from repro.obs.profiling import make_profile_window
 from repro.serve.blockpool import BlockPool
 from repro.serve.config import ServeConfig
@@ -171,8 +180,6 @@ class _Slot:
     prefilling: bool = False
     done: int = 0  # prompt tokens whose KV is resident (chunk start offset)
     row: Optional[np.ndarray] = None  # host physical-id table row
-    admit_wall: float = 0.0  # accumulated chunk wall time (time_admissions)
-    hit: int = 0  # prefix-cache matched tokens at admission
 
     @property
     def prompt_len(self) -> int:
@@ -369,13 +376,6 @@ class Scheduler:
             if config.prefill_chunk and not self._offset and caps["chunked_prefill"]
             else 0
         )
-        self._time_admissions = bool(config.time_admissions)
-        # events / admit_times / the span tracer share trace_capacity and
-        # its oldest-first drop rule (see RingLog)
-        self.admit_times: List[Tuple[int, float, int]] = RingLog(
-            tele.trace_capacity
-        )  # (req, seconds, hit_tokens)
-
         self.caches = self._init_caches()
         # slot-table state lives ON DEVICE: the per-step loop feeds the
         # previous step's device handles straight back and only downloads
@@ -459,6 +459,8 @@ class Scheduler:
             "serve_straggler_fraction", "fraction of decode steps flagged slow by the monitor"
         )
         self._timelines: Dict[int, List[Tuple[str, int]]] = {}
+        # events and the span tracer share trace_capacity and its
+        # oldest-first drop rule (see RingLog)
         self.events: List[Tuple[int, str, int, int]] = RingLog(
             tele.trace_capacity
         )  # (step, kind, req, slot); oldest dropped past trace_capacity
@@ -821,24 +823,32 @@ class Scheduler:
             state.prefilling = True
             state.done = start
             state.row = row
-            state.hit = start
             self.stats["chunked_admissions"] += 1
             self.events.append((self.step_count, "admit", idx, slot))
             return
-        t0 = time.perf_counter() if self._time_admissions else 0.0
-        span = self.tracer.span("admit", step=self.step_count, req=idx, slot=slot, prompt=lp)
+        # prefix hit: prefill only the uncached tail, traced start offset.
+        # Quantized pools route MISSES (start=0) through the same trace so
+        # the first sampled token always comes from quantized-pool
+        # attention — dense-prefill logits would split hit/miss numerics
+        # (DESIGN.md §11).
+        tail_prefill = bool(start) or (self._quant_admit and not req.extras)
+        tail = lp - start
+        bucket = self._bucket(tail if tail_prefill else lp)
+        # the span closes in _activate, once the first token is on the host
+        tr = self.tracer
+        span = (
+            tr.span(
+                "admit", step=self.step_count, req=idx, slot=slot, prompt=lp, bucket=bucket,
+                start=start,
+            )
+            if tr.enabled
+            else NULL_SPAN
+        )
         span.__enter__()
         row = np.zeros(self.max_blocks, np.int32)
         row[: len(blocks)] = np.asarray(blocks, np.int32) + 1  # physical ids
         self._block_tables = self._block_tables.at[slot].set(jnp.asarray(row))
-        if start or (self._quant_admit and not req.extras):
-            # prefix hit: prefill only the uncached tail, traced start offset.
-            # Quantized pools route MISSES (start=0) through the same trace so
-            # the first sampled token always comes from quantized-pool
-            # attention — dense-prefill logits would split hit/miss numerics
-            # (DESIGN.md §11).
-            tail = lp - start
-            bucket = self._bucket(tail)
+        if tail_prefill:
             padded = np.zeros(bucket, np.int32)
             padded[:tail] = prompt[start:]
             admit = self._fns.admit_prefix_step(bucket, self.block_size)
@@ -871,7 +881,6 @@ class Scheduler:
                 self._temp,
             )
             self._buckets_used.add((bucket, self.block_size))
-        span.__exit__(None, None, None)
         self.stats["prefills"] += 1
         # admission_traces: distinct bucketed trace shapes THIS run admitted
         # through (each compiled at most once, engine-memoized across runs);
@@ -885,10 +894,7 @@ class Scheduler:
             # nodes) while the blocks are still pinned by this table
             self.prefix.insert(prompt, blocks, self.eng.params_fingerprint())
             self.stats["prefix_evicted_blocks"] = self.prefix.stats["evicted_blocks"]
-        if self._time_admissions:
-            first_t.block_until_ready()
-            self.admit_times.append((idx, time.perf_counter() - t0, start))
-        self._register(slot, idx, prompt, budget, req, blocks, first_t)
+        self._register(slot, idx, prompt, budget, req, blocks, first_t, span)
 
     def _prefill_chunk(self, slot: int) -> None:
         """Run ONE tail-prefill chunk for a prefilling slot — the §7 traced-
@@ -897,31 +903,42 @@ class Scheduler:
         chunks discard their sampled token (junk past the real tail); the
         final chunk samples the request's first token with the SAME
         (request, step=0) seed one-shot admission uses, then publishes the
-        device table row and activates the slot."""
+        device table row and activates the slot.  A non-final chunk's span
+        covers its dispatch only (nothing waits for it; its device time
+        lands in the next span that syncs); the final chunk's closes in
+        ``_activate``, once the first token is on the host."""
         state = self._slots[slot]
         lp = state.prompt_len
         tail = min(self.chunk, lp - state.done)
         final = state.done + tail == lp
-        t0 = time.perf_counter() if self._time_admissions else 0.0
         bucket = self._bucket(tail)
         padded = np.zeros(bucket, np.int32)
         padded[:tail] = state.prompt[state.done : state.done + tail]
         admit = self._fns.admit_prefix_step(bucket, self.block_size)
-        with self.tracer.span(
-            "chunk", step=self.step_count, req=state.index, slot=slot, done=state.done, tail=tail
-        ):
-            first_t, self.caches = self.eng._with_backend(
-                admit,
-                self.eng.params,
-                {"tokens": jnp.asarray(padded[None])},
-                jnp.int32(tail),
-                jnp.int32(state.done),
-                self.caches,
-                jnp.asarray(state.row),  # device row stays zeroed until final
-                jnp.int32(_sample_seed(state.index, 0)),
-                self._base_key,
-                self._temp,
+        tr = self.tracer
+        span = (
+            tr.span(
+                "chunk", step=self.step_count, req=state.index, slot=slot, done=state.done,
+                tail=tail, bucket=bucket,
             )
+            if tr.enabled
+            else NULL_SPAN
+        )
+        span.__enter__()
+        first_t, self.caches = self.eng._with_backend(
+            admit,
+            self.eng.params,
+            {"tokens": jnp.asarray(padded[None])},
+            jnp.int32(tail),
+            jnp.int32(state.done),
+            self.caches,
+            jnp.asarray(state.row),  # device row stays zeroed until final
+            jnp.int32(_sample_seed(state.index, 0)),
+            self._base_key,
+            self._temp,
+        )
+        if not final:
+            span.__exit__(None, None, None)
         self._buckets_used.add(("prefix", bucket, self.block_size))
         state.done += tail
         tl = self._timelines.get(state.index)
@@ -931,9 +948,6 @@ class Scheduler:
         self.stats["admission_traces"] = len(self._buckets_used)
         self.stats["admission_trace_compiles"] = self._fns.admit_compiles - self._compiles0
         self.stats["chunk_trace_compiles"] = self._fns.prefix_compiles - self._prefix_compiles0
-        if self._time_admissions:
-            first_t.block_until_ready()
-            state.admit_wall += time.perf_counter() - t0
         if not final:
             return
         self.stats["prefills"] += 1
@@ -943,9 +957,7 @@ class Scheduler:
             # earlier would expose half-prefilled blocks to other admissions
             self.prefix.insert(state.prompt, state.blocks, self.eng.params_fingerprint())
             self.stats["prefix_evicted_blocks"] = self.prefix.stats["evicted_blocks"]
-        if self._time_admissions:
-            self.admit_times.append((state.index, state.admit_wall, state.hit))
-        self._activate(slot, first_t)
+        self._activate(slot, first_t, span)
 
     def _advance_prefills(self) -> None:
         """The mixed-batch chunk pass: one prefill chunk per prefilling slot
@@ -964,19 +976,22 @@ class Scheduler:
         req: Request,
         blocks: List[int],
         first_t,
+        span,
     ) -> None:
         """Slot bookkeeping after the fused one-shot admission dispatch."""
         self._new_slot(slot, idx, prompt, budget, req, blocks)
         self.events.append((self.step_count, "admit", idx, slot))
-        self._activate(slot, first_t)
+        self._activate(slot, first_t, span)
 
-    def _activate(self, slot: int, first_t) -> None:
+    def _activate(self, slot: int, first_t, span) -> None:
         """Flip a slot live once the full prompt's KV is resident and its
         first token is sampled: publish the device slot-table row state the
         decode dispatch reads, record the first token, and apply the
-        instant finish checks (budget-1 / immediate EOS)."""
+        instant finish checks (budget-1 / immediate EOS).  ``span``, the
+        admission's, closes once the first token is on the host."""
         state = self._slots[slot]
         first = int(np.asarray(first_t))
+        span.__exit__(None, None, None)
         state.prefilling = False
         state.out.append(first)
         state.first_token_step = self.step_count
@@ -1053,6 +1068,16 @@ class Scheduler:
         ``horizon=0`` (one write at ``pos``); the speculative controller
         reserves its whole draft window up front so a verify trace never
         writes through a missing table entry."""
+        tr = self.tracer
+        if not tr.enabled:
+            self._grow_rows(horizon)
+            return
+        allocs0 = self.pool.total_allocs
+        with tr.span("grow", step=self.step_count) as span:
+            self._grow_rows(horizon)
+            span.args["new_blocks"] = self.pool.total_allocs - allocs0
+
+    def _grow_rows(self, horizon: int) -> None:
         order = sorted(
             (s for s in range(self.n_slots) if self._slots[s] is not None),
             key=lambda s: (self._slots[s].admitted_step, self._slots[s].index),
@@ -1087,6 +1112,38 @@ class Scheduler:
     def _n_decoding(self) -> int:
         """Live slots past their prefill (the decode dispatch's real rows)."""
         return sum(1 for st in self._slots if st is not None and not st.prefilling)
+
+    def _step_span(self):
+        """The ``step`` span (``NULL_SPAN`` with tracing off); its counters
+        are filled in by ``_count_step``."""
+        tr = self.tracer
+        return tr.span("step", step=self.step_count) if tr.enabled else NULL_SPAN
+
+    def _count_step(self, span) -> None:
+        """The step span's counters, read once the step's growth,
+        admissions and chunks are in: the rows its decode runs over, and
+        the pool at its fullest within the step."""
+        if self.tracer.enabled:
+            span.args.update(
+                live=self._n_live,
+                decoding=self._n_decoding(),
+                queued=len(self._queue),
+                blocks_live=self.pool.n_live,
+            )
+
+    @contextlib.contextmanager
+    def _emit_span(self, step: int):
+        """The ``emit`` span around a step's post-dispatch loop, with the
+        tokens it emitted and the requests it finished."""
+        tr = self.tracer
+        if not tr.enabled:
+            yield
+            return
+        tokens0, finished0 = self.stats["tokens_emitted"], self.stats["evictions"]
+        with tr.span("emit", step=step) as span:
+            yield
+            span.args["tokens"] = self.stats["tokens_emitted"] - tokens0
+            span.args["finished"] = self.stats["evictions"] - finished0
 
     def _sync_gauges(self) -> None:
         """Refresh the point-in-time occupancy gauges (host ints, per step)."""
@@ -1130,11 +1187,17 @@ class Scheduler:
         once the queue is drained and every slot is idle."""
         if self._profile is not None:
             self._profile.on_step()
+        with self._step_span() as span:
+            return self._step(span)
+
+    def _step(self, span) -> bool:
+        n = self.step_count
         self._grow_tables()
         self._admit()
         if self.prefix is not None:
             self.stats["prefix_evicted_blocks"] = self.prefix.stats["evicted_blocks"]
         if self._n_live == 0:
+            self._count_step(span)
             if not self._queue:
                 self._sync_gauges()
                 return False
@@ -1146,7 +1209,9 @@ class Scheduler:
             return True
 
         self._advance_prefills()
-        if self._n_decoding() == 0:
+        self._count_step(span)
+        n_decoding = self._n_decoding()
+        if n_decoding == 0:
             # every live slot is mid-prefill (or finished at activation):
             # the chunk pass above was this step's work; time still advances
             self.step_count += 1
@@ -1154,9 +1219,12 @@ class Scheduler:
             self._sync_gauges()
             return bool(self._n_live or self._queue)
 
+        tr = self.tracer
         t0 = time.perf_counter()
-        with self.tracer.span(
-            "decode", step=self.step_count, n_decode=self._n_decoding(), n_live=self._n_live
+        with (
+            tr.span("decode", step=n, n_decode=n_decoding, n_live=self._n_live)
+            if tr.enabled
+            else NULL_SPAN
         ):
             self._tokens, self._pos, self.caches = self.eng._with_backend(
                 self._fns.decode_step,
@@ -1177,19 +1245,20 @@ class Scheduler:
         self.stats["decode_trace_compiles"] = self._fns.decode_cache_size() - self._decode_cache0
         self._observe_step_time(dt)
 
-        for s, state in enumerate(self._slots):
-            if state is None or state.prefilling:
-                continue
-            state.pos += 1  # mirror of the device's pos + active
-            tok = int(nxt[s])
-            state.out.append(tok)
-            self.stats["tokens_emitted"] += 1
-            self._h_itl.observe(dt)
-            self._emit_tokens(state)
-            if tok == state.eos_id:
-                self._finish(s, "eos")
-            elif len(state.out) >= state.budget:
-                self._finish(s, "length")
+        with self._emit_span(n):
+            for s, state in enumerate(self._slots):
+                if state is None or state.prefilling:
+                    continue
+                state.pos += 1  # mirror of the device's pos + active
+                tok = int(nxt[s])
+                state.out.append(tok)
+                self.stats["tokens_emitted"] += 1
+                self._h_itl.observe(dt)
+                self._emit_tokens(state)
+                if tok == state.eos_id:
+                    self._finish(s, "eos")
+                elif len(state.out) >= state.budget:
+                    self._finish(s, "length")
         self._sync_gauges()
         return bool(self._n_live or self._queue)
 

@@ -69,6 +69,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.lm import decode_lm, decode_verify_lm
+from repro.obs import NULL_SPAN
 from repro.serve.config import ServeConfig
 from repro.serve.engine import filter_logits
 from repro.serve.scheduler import Scheduler, _sample_seed
@@ -385,32 +386,36 @@ class SpeculativeScheduler(Scheduler):
             return super().step()
         if self._profile is not None:
             self._profile.on_step()
-        # growth runs twice: existing rows reserve their draft windows
-        # before admission spends blocks (the §6 step-order rule), and a
-        # second pass covers freshly admitted rows' windows — under
-        # pressure it may preempt the youngest (correct: replay is exact)
-        self._grow_tables(horizon=self._depth())
-        self._admit()
-        depth = self._depth()
-        self._grow_tables(horizon=depth)
-        if self._n_live == 0:
-            if not self._queue:
+        with self._step_span() as span:
+            # growth runs twice: existing rows reserve their draft windows
+            # before admission spends blocks (the §6 step-order rule), and a
+            # second pass covers freshly admitted rows' windows — under
+            # pressure it may preempt the youngest (correct: replay is exact)
+            self._grow_tables(horizon=self._depth())
+            self._admit()
+            depth = self._depth()
+            self._grow_tables(horizon=depth)
+            self._count_step(span)
+            if self._n_live == 0:
+                if not self._queue:
+                    self._sync_gauges()
+                    return False
+                self.step_count += 1
+                self.stats["idle_steps"] += 1
                 self._sync_gauges()
-                return False
-            self.step_count += 1
-            self.stats["idle_steps"] += 1
+                return True
+            self._spec_round(depth)
             self._sync_gauges()
-            return True
-        self._spec_round(depth)
-        self._sync_gauges()
-        return bool(self._n_live or self._queue)
+            return bool(self._n_live or self._queue)
 
     def _spec_round(self, k: int) -> None:
         fns, eng = self.spec_fns, self.eng
         greedy = self.temperature <= 0.0
         draft_key = jax.random.fold_in(self._base_key, _DRAFT_TAG)
+        n = self.step_count
+        tr = self.tracer
         t0 = time.perf_counter()
-        span = self.tracer.span("verify", step=self.step_count, k=k, n_live=self._n_live)
+        span = tr.span("verify", step=n, k=k, n_live=self._n_live) if tr.enabled else NULL_SPAN
         span.__enter__()
         # draft phase: k+1 single-token self-decode steps on the draft pool
         # (chained on device, no host sync).  The (k+1)-th step only writes
@@ -466,40 +471,41 @@ class SpeculativeScheduler(Scheduler):
         self.stats["verify_trace_compiles"] = fns.verify_compiles - self._verify_compiles0
         self._observe_step_time(dt)
 
-        for s in range(self.n_slots):
-            state = self._slots[s]
-            if state is None:
-                continue
-            accepted = int(m_np[s])
-            # commits: accepted drafts then the verify's correction/bonus
-            # token, truncated by the row's budget and an in-stream EOS
-            ncommit = min(accepted + 1, state.budget - len(state.out))
-            toks = [int(t) for t in out_np[s, :ncommit]]
-            if state.eos_id >= 0 and state.eos_id in toks:
-                toks = toks[: toks.index(state.eos_id) + 1]
-                ncommit = len(toks)
-            state.out.extend(toks)
-            state.pos += ncommit
-            self._emit_tokens(state)
-            self.stats["tokens_emitted"] += ncommit
-            self._h_accept.observe(ncommit)
-            per_tok = dt / max(1, ncommit)  # this row's per-token wall time view
-            for _ in range(ncommit):
-                self._h_itl.observe(per_tok)
-            self.stats["spec_accepted"] += min(accepted, ncommit)
-            self.stats["spec_emitted"] += ncommit
-            self.stats["spec_row_rounds"] += 1
-            rounds, committed = self._slot_spec.get(s, (0, 0))
-            self._slot_spec[s] = (rounds + 1, committed + ncommit)
-            if self._adaptive:
-                # AIMD: one deeper after a clean round, shrink to what was
-                # accepted (floor 1) after a rejection
-                grown = min(self.draft_k, k + 1)
-                self._adaptive_k[s] = grown if accepted >= k else max(1, accepted)
-            if toks[-1] == state.eos_id:
-                self._finish(s, "eos")
-            elif len(state.out) >= state.budget:
-                self._finish(s, "length")
+        with self._emit_span(n):
+            for s in range(self.n_slots):
+                state = self._slots[s]
+                if state is None:
+                    continue
+                accepted = int(m_np[s])
+                # commits: accepted drafts then the verify's correction/bonus
+                # token, truncated by the row's budget and an in-stream EOS
+                ncommit = min(accepted + 1, state.budget - len(state.out))
+                toks = [int(t) for t in out_np[s, :ncommit]]
+                if state.eos_id >= 0 and state.eos_id in toks:
+                    toks = toks[: toks.index(state.eos_id) + 1]
+                    ncommit = len(toks)
+                state.out.extend(toks)
+                state.pos += ncommit
+                self._emit_tokens(state)
+                self.stats["tokens_emitted"] += ncommit
+                self._h_accept.observe(ncommit)
+                per_tok = dt / max(1, ncommit)  # this row's per-token wall time view
+                for _ in range(ncommit):
+                    self._h_itl.observe(per_tok)
+                self.stats["spec_accepted"] += min(accepted, ncommit)
+                self.stats["spec_emitted"] += ncommit
+                self.stats["spec_row_rounds"] += 1
+                rounds, committed = self._slot_spec.get(s, (0, 0))
+                self._slot_spec[s] = (rounds + 1, committed + ncommit)
+                if self._adaptive:
+                    # AIMD: one deeper after a clean round, shrink to what was
+                    # accepted (floor 1) after a rejection
+                    grown = min(self.draft_k, k + 1)
+                    self._adaptive_k[s] = grown if accepted >= k else max(1, accepted)
+                if toks[-1] == state.eos_id:
+                    self._finish(s, "eos")
+                elif len(state.out) >= state.budget:
+                    self._finish(s, "length")
 
         # rollback: rejected positions keep stale KV the position mask
         # hides; the device mirrors are refreshed from the host's committed

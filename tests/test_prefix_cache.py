@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from repro import configs, core
 from repro.models.lm import init_lm
 from repro.models.quantized import set_packed_backend
-from repro.serve import Request, ServeConfig, ServeEngine
+from repro.serve import Request, ServeConfig, ServeEngine, TelemetryConfig
 
 MAX_LEN = 24
 _ENGINES = {}
@@ -243,18 +243,22 @@ def test_preempted_restart_hits_its_own_blocks(rng, unpack_backend):
 
 
 def test_admission_timing_surfaces_hits(rng, unpack_backend):
-    """time_admissions records per-admission wall time and hit offsets —
-    the serve_prefix_cache bench's TTFT source."""
+    """Traced admit spans record per-admission wall time and hit offsets
+    (``start``, the tokens already cached) — the serve_prefix_cache
+    bench's TTFT source."""
     eng = _engines("internlm2-1.8b")[0]
     prompt = _prompt(rng, 8, eng.cfg.vocab_size)
     reqs = [Request(tokens=prompt, max_new_tokens=3) for _ in range(3)]
     comps, sched = eng.serve(
         reqs,
-        ServeConfig(n_slots=3, block_size=4, prefix_cache=True, time_admissions=True),
+        ServeConfig(
+            n_slots=3, block_size=4, prefix_cache=True, telemetry=TelemetryConfig(trace=True)
+        ),
         return_scheduler=True,
     )
     _assert_exact(eng, reqs, comps)
-    assert len(sched.admit_times) == 3
-    assert sched.admit_times[0][2] == 0  # first admission was a miss
-    assert all(start > 0 for _, _, start in sched.admit_times[1:])
-    assert all(dt > 0 for _, dt, _ in sched.admit_times)
+    admits = [(dur, args) for kind, _, dur, args in sched.tracer.spans if kind == "admit"]
+    assert len(admits) == 3
+    assert admits[0][1]["start"] == 0  # first admission was a miss
+    assert all(args["start"] > 0 for _, args in admits[1:])
+    assert all(dur > 0 for dur, _ in admits)

@@ -260,7 +260,7 @@ def test_trace_spans_cover_serve_phases(rng, unpack_backend, tmp_path):
     doc = json.loads(path.read_text())
     assert {e["name"] for e in doc["traceEvents"]} >= {"admit", "decode", "evict"}
     # ring capacity bounds the event logs too, with the same drop semantics
-    assert sched.events.capacity == sched.admit_times.capacity == 512
+    assert sched.events.capacity == 512
     # tracing changed no tokens
     off = eng.serve(reqs, ServeConfig(n_slots=2, block_size=4, n_blocks=6))
     assert [c.tokens for c in comps] == [c.tokens for c in off]
