@@ -10,17 +10,25 @@ computes that block's QK^T / softmax / PV contribution, and folds it into
 the running (max, denominator, accumulator) — FlashAttention's recurrence
 over the POOL's blocks, so the logical view never exists anywhere.
 
-Grid and blocks (GQA kernel):
+Grid and blocks (GQA kernel), kf = heads_per_step(K, T·G) KV heads a step:
 
-  grid = (B, K, max_blocks)          j innermost: scratch carries across j
-  q    (B, K, T·G, hd)   block (-, -, T·G, hd)  index (b, kh, 0, 0)
-  k/v  (n_blocks, K, block, hd) block (-, -, block, hd)
-                                     index (block_tables[b, j], kh, 0, 0)
-  out  (B, K, T·G, hd)   block (-, -, T·G, hd)  written on the last j
+  grid = (B, K/kf, max_blocks)       j innermost: scratch carries across j
+  q    (B, K, T·G, hd)   block (-, kf, T·G, hd)  index (b, kg, 0, 0)
+  k/v  (n_blocks, K, block, hd) block (-, kf, block, hd)
+                                     index (block_tables[b, j], kg, 0, 0)
+  out  (B, K, T·G, hd)   block (-, kf, T·G, hd)  written on the last j
 
-The pools are head-major inside a physical block, so every block's two
-minor dims are whole (block, hd) tiles — the (8, 128) rule Mosaic holds
-every block to (``-`` marks a squeezed unit dim).
+The pools are head-major inside a physical block, so a step's kf heads of
+one block are one contiguous (kf, block, hd) slab — one DMA per pool per
+step — and every block's two minor dims are whole (block, hd) tiles, the
+(8, 128) rule Mosaic holds every block to (``-`` marks a squeezed unit
+dim).  A grid step costs a fixed ~0.45 µs on a TPU v5e whatever it carries,
+so the kernel folds as many heads into a step as keeps kf·T·G within
+ROWS_PER_STEP: all K at decode, fewer at the larger tail-prefill buckets,
+one (a step per head) where T·G alone reaches the limit.  The step's heads
+are the batch axis of its dots, so each head runs the same per-head
+recurrence; the call's name carries the fold (``paged_attention_int8_h8``),
+so a trace splits the kernel's time by it.
 
 ``block_tables`` (and the per-row first query position + window) ride as
 scalar-prefetch operands (PrefetchScalarGridSpec), so the index_map reads
@@ -38,9 +46,9 @@ the kernel (× 2^-KV_F, an exponent shift) — ``kv_scale`` is static on the
 pool dtype.  Per-block SYMOG pools (DESIGN.md §11) instead carry int32
 exponent leaves: the ``_quant`` kernel variants read the physical block's
 (1, K) exponent row through the SAME prefetched table as the data block
-and pick head kh with a masked lane sum, unpack packed int4 words with a
-lane concatenate (core.packing split planes), and dequantize with one
-exp2 multiply inside the loop.
+and pick each of the step's heads with a masked lane sum, unpack packed
+int4 words with a lane concatenate (core.packing split planes), and
+dequantize with one exp2 multiply inside the loop.
 
 The online recurrence per block j (m running max, l denominator, o acc):
 
@@ -69,20 +77,37 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.packing import unpack_planes
 
 NEG_INF = -1e30  # matches the composed path's masked-logit fill
+# T·G query rows of the largest tail-prefill call that runs on the chip
+# (a 2048-token bucket x G=2): a folded step holds no more rows than it
+ROWS_PER_STEP = 4096
+
+
+def heads_per_step(K: int, tg: int) -> int:
+    """KV heads one GQA grid step carries: the largest divisor kf of the
+    (shard-local) K with kf·tg <= ROWS_PER_STEP, and 1 where tg alone
+    reaches it."""
+    fits = [d for d in range(1, K + 1) if K % d == 0 and d * tg <= ROWS_PER_STEP]
+    return max(fits, default=1)
+
+
+def _dot(a, b, contract):
+    """f32-accumulated dot over ``contract`` ((lhs dim,), (rhs dim,)),
+    batched over a leading head axis where the operands carry one."""
+    batch = ((0,), (0,)) if a.ndim == 3 else ((), ())
+    return jax.lax.dot_general(a, b, (contract, batch), preferred_element_type=jnp.float32)
 
 
 def _online_update(mask, s, v, m_ref, l_ref, acc_ref):
-    """One block's fold into the running (max, denom, acc) scratch."""
+    """One block's fold into the running (max, denom, acc) scratch; with a
+    leading head axis, each head's fold runs side by side."""
     s = jnp.where(mask, s, NEG_INF)
     m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
     m_ref[...] = m_new
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + _dot(p, v, ((p.ndim - 1,), (v.ndim - 2,)))
 
 
 def _init_scratch(m_ref, l_ref, acc_ref):
@@ -100,46 +125,50 @@ def _finish(o_ref, l_ref, acc_ref):
 
 
 def _qk(q, k):
-    """q (rows, d) · k (block, d)^T without materializing the transpose."""
-    return jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    """q (rows, d) · k (block, d)^T without materializing the transpose
+    (per head: q (h, rows, d), k (h, block, d))."""
+    return _dot(q, k, ((q.ndim - 1,), (k.ndim - 1,)))
 
 
 def _dequant(words, exp, kv_bits):
-    """int8 mantissa words (int4: two per word) × 2^exp, exp a (1, 1) array."""
+    """int8 mantissa words (int4: two per word) × 2^exp, exp a (1, 1) array
+    or one per head, (h, 1, 1)."""
     m = unpack_planes(words, 4) if kv_bits == 4 else words.astype(jnp.int32)
     return m.astype(jnp.float32) * jnp.exp2(exp.astype(jnp.float32))
 
 
-def _head_exp(e_ref, kh):
-    """This head's exponent from the block's (1, K) exponent row, as (1, 1)."""
-    e = e_ref[...]
-    lane = jax.lax.broadcasted_iota(jnp.int32, e.shape, 1)
-    return jnp.sum(jnp.where(lane == kh, e, 0), axis=1, keepdims=True)
+def _heads_exp(e_ref, kg, kf):
+    """Exponents of heads kg·kf .. kg·kf + kf - 1 from the block's (1, K)
+    exponent row, as (kf, 1, 1): a masked lane sum per head."""
+    e = jnp.broadcast_to(e_ref[...][None], (kf, 1, e_ref.shape[-1]))
+    lane = jax.lax.broadcasted_iota(jnp.int32, e.shape, 2)
+    head = kg * kf + jax.lax.broadcasted_iota(jnp.int32, e.shape, 0)
+    return jnp.sum(jnp.where(lane == head, e, 0), axis=2, keepdims=True)
 
 
 def _attn_kernel(bt_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, *rest,
-                 block: int, nb: int, g: int, scale: float, cap: float,
-                 kv_scale: float, kv_bits: int):
-    """GQA body.  Float / KV_F int8 pools scale by the static ``kv_scale``;
-    per-block-scale pools (``kv_bits`` 8 or 4) carry two exponent operands
-    ahead of the output and dequantize with this block's head exponent."""
+                 block: int, nb: int, g: int, kf: int, scale: float,
+                 cap: float, kv_scale: float, kv_bits: int):
+    """GQA body over a step's kf KV heads, the head axis the batch axis of
+    every dot: each head runs the per-head recurrence.  Float / KV_F int8
+    pools scale by the static ``kv_scale``; per-block-scale pools
+    (``kv_bits`` 8 or 4) carry two exponent operands ahead of the output
+    and dequantize each head with its own exponent."""
     if kv_bits:
         ke_ref, ve_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
-    b, kh, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, kg, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         _init_scratch(m_ref, l_ref, acc_ref)
 
-    tg = q_ref.shape[0]
+    tg = q_ref.shape[1]
     q = q_ref[...].astype(jnp.float32)
     if kv_bits:
-        k = _dequant(k_ref[...], _head_exp(ke_ref, kh), kv_bits)
-        v = _dequant(v_ref[...], _head_exp(ve_ref, kh), kv_bits)
+        k = _dequant(k_ref[...], _heads_exp(ke_ref, kg, kf), kv_bits)
+        v = _dequant(v_ref[...], _heads_exp(ve_ref, kg, kf), kv_bits)
     else:
         k = k_ref[...].astype(jnp.float32)
         v = v_ref[...].astype(jnp.float32)
@@ -151,8 +180,8 @@ def _attn_kernel(bt_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, *rest,
     if cap > 0:
         s = jnp.tanh(s / cap) * cap
 
-    q_pos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (tg, 1), 0) // g
-    kv_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    q_pos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (1, tg, 1), 1) // g
+    kv_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block), 2)
     mask = (kv_pos <= q_pos) & (q_pos - kv_pos < win_ref[0])
     _online_update(mask, s, v, m_ref, l_ref, acc_ref)
 
@@ -173,38 +202,41 @@ def paged_attention_padded(q, k_pool, v_pool, block_tables, pos0, window, *,
     Per-block-scale pools pass ``k_exp``/``v_exp`` (n_blocks, K) int32
     exponents plus ``kv_bits`` (8, or 4 for packed pools whose last dim is
     hd/2); each grid step reads its physical block's exponent row through
-    the same scalar-prefetched table as the data block."""
+    the same scalar-prefetched table as the data block.  A step carries
+    ``heads_per_step(K, T·G)`` KV heads of one row and one table block."""
     B, K, tg, hd = q.shape
     block = k_pool.shape[2]
     nb = block_tables.shape[1]
     hdw = k_pool.shape[3]  # hd, or hd//2 for packed int4 words
     quant = k_exp is not None
+    kf = heads_per_step(K, tg)
     kv_spec = pl.BlockSpec(
-        (None, None, block, hdw), lambda b, kh, j, bt, pos, win: (bt[b, j], kh, 0, 0)
+        (None, kf, block, hdw), lambda b, kg, j, bt, pos, win: (bt[b, j], kg, 0, 0)
     )
-    q_spec = pl.BlockSpec((None, None, tg, hd), lambda b, kh, j, bt, pos, win: (b, kh, 0, 0))
+    q_spec = pl.BlockSpec((None, kf, tg, hd), lambda b, kg, j, bt, pos, win: (b, kg, 0, 0))
     in_specs = [q_spec, kv_spec, kv_spec]
     operands = [q, k_pool, v_pool]
     if quant:
-        e_spec = pl.BlockSpec((None, 1, K), lambda b, kh, j, bt, pos, win: (bt[b, j], 0, 0))
+        e_spec = pl.BlockSpec((None, 1, K), lambda b, kg, j, bt, pos, win: (bt[b, j], 0, 0))
         in_specs += [e_spec, e_spec]
         operands += [k_exp.reshape(-1, 1, K), v_exp.reshape(-1, 1, K)]
     body = functools.partial(
-        _attn_kernel, block=block, nb=nb, g=g, scale=scale, cap=cap,
+        _attn_kernel, block=block, nb=nb, g=g, kf=kf, scale=scale, cap=cap,
         kv_scale=kv_scale, kv_bits=kv_bits if quant else 0,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, K, nb),
+        grid=(B, K // kf, nb),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((tg, 1), jnp.float32),
-            pltpu.VMEM((tg, 1), jnp.float32),
-            pltpu.VMEM((tg, hd), jnp.float32),
+            pltpu.VMEM((kf, tg, 1), jnp.float32),
+            pltpu.VMEM((kf, tg, 1), jnp.float32),
+            pltpu.VMEM((kf, tg, hd), jnp.float32),
         ],
     )
-    name = f"paged_attention_int{kv_bits}" if quant else "paged_attention"
+    base = f"paged_attention_int{kv_bits}" if quant else "paged_attention"
+    name = f"{base}_h{kf}"
     return pl.pallas_call(
         body,
         grid_spec=grid_spec,

@@ -8,7 +8,9 @@ masked softmax) it fuses away:
 
   - kernel vs pure-jnp oracle across block sizes {8, 16}, GQA/MQA head
     layouts, sliding window + softcap (gemma2), multi-token query rows
-    (the verify pass), int8 fixed-point pools and bf16 inputs;
+    (the verify pass), int8 fixed-point pools and bf16 inputs, with a grid
+    step carrying all K KV heads, one, or a part of them
+    (``heads_per_step``), on one device and on a simulated mesh;
   - the MLA absorbed-decode variant against its oracle;
   - the real layer entry points (attn_decode / attn_verify_paged /
     attn_prefill_paged / mla_decode / mla_verify_paged) under the
@@ -19,6 +21,11 @@ masked softmax) it fuses away:
   - end-to-end: greedy serve() over the fused backend is token-identical
     to ``generate_static`` (which always runs the dense uniform-pos path).
 """
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -28,6 +35,7 @@ import jax.numpy as jnp
 from repro.core.packing import pack_int
 from repro.kernels.dispatch import set_attention_backend
 from repro.kernels.paged_attention import paged_attention, paged_attention_mla
+from repro.kernels.paged_attention.kernel import ROWS_PER_STEP, heads_per_step
 from repro.kernels.paged_attention.ref import (
     gather_logical,
     paged_attention_mla_ref,
@@ -70,6 +78,25 @@ def fused_interpret():
     set_attention_backend("fused-interpret")
     yield
     set_attention_backend("auto")
+
+
+def _per_head_exponents(e):
+    """Give each KV head of a block its own exponent (head 0's, shifted by
+    the head index), so a head that dequantized with a neighbour's would be
+    off by a power of two."""
+    K = e.shape[1]
+    return e[:, :1] + jnp.arange(K, dtype=e.dtype) - K // 2
+
+
+def _heads(layout, T):
+    """(K, G, kf) of a layout: K KV heads of G queries, kf of them a grid
+    step.  ``foldN`` widens T·G to ROWS_PER_STEP / N rows so that a step
+    carries N of 4 heads: the fold is forced through the shape alone."""
+    if layout.startswith("fold"):
+        kf = int(layout[4:])
+        return 4, ROWS_PER_STEP // (kf * T), kf
+    K, G = {"gqa": (2, 2), "mqa": (1, 4), "mha": (4, 1)}[layout]
+    return K, G, K
 
 
 def _tables(key, B, max_blocks, n_blocks):
@@ -117,10 +144,14 @@ def _assert_close(a, b, dtype=jnp.float32):
         ("gqa", 4, None, 0.0),  # verify pass: K+1 query rows
         ("gqa", 4, 7, 0.0),  # windowed verify
         ("mha", 3, None, 0.0),  # G=1, every head its own KV
+        ("fold2", 1, 5, 8.0),  # 2 of 4 heads a step, window + softcap
+        ("fold2", 4, 7, 0.0),  # 2 of 4 heads a step, windowed verify
+        ("fold1", 2, None, 0.0),  # rows fill a step: one head a step
     ],
 )
 def test_kernel_matches_reference(block, layout, T, window, cap, rng):
-    K, G = {"gqa": (2, 2), "mqa": (1, 4), "mha": (4, 1)}[layout]
+    K, G, kf = _heads(layout, T)
+    assert heads_per_step(K, T * G) == kf
     q, kp, vp, bt, pos0 = _case(
         jax.random.fold_in(rng, block), B=3, T=T, K=K, G=G, hd=16,
         block=block, max_blocks=3,
@@ -156,20 +187,27 @@ def test_kernel_int8_fixed_point_pools(block, rng):
         ("gqa", 1, 5, 8.0),  # sliding window + softcap
         ("mqa", 1, None, 0.0),  # K=1 multi-query
         ("gqa", 4, 7, 0.0),  # windowed verify rows
+        ("mha", 3, None, 0.0),  # all 4 heads a step
+        ("fold2", 1, 5, 8.0),  # 2 of 4 heads a step
+        ("fold1", 1, None, 0.0),  # one head a step
     ],
 )
 def test_kernel_per_block_quantized_pools(bits, layout, T, window, cap, rng):
     """DESIGN.md §11: per-(block, head) exponent dequantization — and the
     int4 word unpack — happen INSIDE the online-softmax loop.  The oracle
     gets the SAME quantized pool + exponents, so parity is exact to kernel
-    tolerance (the quantized pool is its own oracle)."""
-    K, G = {"gqa": (2, 2), "mqa": (1, 4)}[layout]
+    tolerance (the quantized pool is its own oracle).  Every head of a block
+    carries its own exponent, so a folded step that dequantized a head with
+    a neighbour's exponent would fail."""
+    K, G, kf = _heads(layout, T)
+    assert heads_per_step(K, T * G) == kf
     q, kp, vp, bt, pos0 = _case(
         jax.random.fold_in(rng, bits), B=3, T=T, K=K, G=G, hd=16,
         block=8, max_blocks=3,
     )
     k_q, ke = _quant_pool(kp, bits)
     v_q, ve = _quant_pool(vp, bits)
+    ke, ve = _per_head_exponents(ke), _per_head_exponents(ve)
     assert k_q.dtype == jnp.int8
     assert k_q.shape[-1] == (8 if bits == 4 else 16)
     kw = dict(scale=16**-0.5, cap=cap, window=window,
@@ -177,6 +215,70 @@ def test_kernel_per_block_quantized_pools(bits, layout, T, window, cap, rng):
     got = paged_attention(q, k_q, v_q, bt, pos0, interpret=True, **kw)
     want = paged_attention_ref(q, k_q, v_q, bt, pos0, **kw)
     _assert_close(got, want)
+
+
+def test_heads_per_step():
+    """The fold is the largest divisor of K whose rows fit a step, and 1
+    where T·G alone fills it."""
+    for K in range(1, 17):
+        for tg in (1, 2, 3, 512, 1000, 1024, 1025, 2048, 2049, 4096, 5000):
+            kf = heads_per_step(K, tg)
+            assert K % kf == 0
+            assert kf == 1 or kf * tg <= ROWS_PER_STEP
+            assert not any(K % d == 0 and d * tg <= ROWS_PER_STEP for d in range(kf + 1, K + 1))
+    assert heads_per_step(1, 2) == 1
+    # internlm2-1.8b (K=8, G=2): decode, then the tail-prefill buckets
+    assert heads_per_step(8, 1 * 2) == 8
+    assert [heads_per_step(8, t * 2) for t in (256, 512, 1024, 2048)] == [8, 4, 2, 1]
+    assert heads_per_step(16, 1) == 16  # olmoe-1b-7b decode (MHA, K=16)
+
+
+_MESH_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.kernels.paged_attention import paged_attention
+    from repro.kernels.paged_attention.kernel import heads_per_step
+    from repro.kernels.paged_attention.ref import paged_attention_ref
+    from repro.models.attention import KV_QMAX, block_scale_exp, quantize_fixed
+    from repro.nn.sharding import make_mesh
+
+    B, T, K, G, hd, block, max_blocks = 2, 1, 8, 2, 16, 8, 3
+    mesh = make_mesh((2, 2), ("data", "model"))
+    assert heads_per_step(K // 2, T * G) == 4  # each shard folds its 4 heads
+    n_blocks = B * max_blocks + 1
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    q = jax.random.normal(ks[0], (B, T, K, G, hd))
+    pools, exps = [], []
+    for k in ks[1:3]:
+        x = jax.random.normal(k, (n_blocks, K, block, hd))
+        e = block_scale_exp(x[:, :, 0], KV_QMAX[8])
+        pools.append(quantize_fixed(x, e[..., None], KV_QMAX[8]))
+        exps.append(e[:, :1] + jnp.arange(K, dtype=e.dtype) - K // 2)  # one per head
+    bt = jax.random.permutation(ks[3], jnp.arange(1, n_blocks))[: B * max_blocks]
+    bt = bt.reshape(B, max_blocks).astype(jnp.int32)
+    pos0 = jax.random.randint(ks[4], (B,), 0, max_blocks * block).astype(jnp.int32)
+    kw = dict(scale=0.25, window=11, k_scale_exp=exps[0], v_scale_exp=exps[1], kv_bits=8)
+    want = paged_attention_ref(q, *pools, bt, pos0, **kw)
+    with jax.set_mesh(mesh):
+        got = jax.jit(lambda q: paged_attention(q, *pools, bt, pos0, interpret=True, **kw))(q)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)
+    print("OK")
+""")
+
+
+def test_kernel_folds_local_heads_under_mesh():
+    """Under a (data 2, model 2) mesh each shard's kernel sees K/2 = 4 local
+    KV heads and folds all four into a step, reading its local slice of the
+    per-head exponents (run in a subprocess on 4 simulated CPU devices)."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    r = subprocess.run(
+        [sys.executable, "-c", _MESH_SCRIPT], capture_output=True, text=True,
+        timeout=600, env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+        cwd=root,
+    )
+    assert r.returncode == 0, r.stdout + "\n" + r.stderr
+    assert "OK" in r.stdout
 
 
 @pytest.mark.parametrize("bits", [8, 4])

@@ -20,6 +20,7 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P, Sing
 
 from repro.core.packing import values_per_byte
 from repro.kernels.fixedpoint_matmul.ops import fixedpoint_matmul, fixedpoint_matmul_experts
+from repro.kernels.paged_attention.kernel import heads_per_step
 from repro.kernels.paged_attention.ops import paged_attention, paged_attention_mla
 
 
@@ -97,13 +98,26 @@ def test_fixedpoint_matmul_experts_compiles(one_chip, k, n):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("t", [1, 4])
+# (B, T, block, physical blocks, table blocks) by case: 16-token blocks at
+# decode (T=1) and a short verify/tail window (T=4); then the internlm2-chat
+# cell's 32-row decode and a 2048-token tail prefill on its 64-token pool
+_PAGED_SHAPES = {
+    1: (4, 1, 16, 129, 32),
+    4: (4, 4, 16, 129, 32),
+    "chat-decode": (32, 1, 64, 1537, 48),
+    "chat-prefill": (1, 2048, 64, 1537, 48),
+}
+
+
+@pytest.mark.parametrize("t", list(_PAGED_SHAPES))
 @pytest.mark.parametrize("pool", ["bf16", "int8", "int4"])
 def test_paged_attention_compiles(one_chip, pool, t):
-    """internlm2-1.8b heads: K=8 KV heads of G=2 queries, head_dim 128,
-    16-token blocks; decode (T=1) and a short verify/tail window."""
+    """internlm2-1.8b heads: K=8 KV heads of G=2 queries, head_dim 128.  The
+    chat shapes compile the fold of all 8 heads into a step (decode) and the
+    one-head step that 4096 query rows leave (2048-token prefill)."""
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    B, K, G, hd, block, n_phys, max_blocks = 4, 8, 2, 128, 16, 129, 32
+    K, G, hd = 8, 2, 128
+    B, t, block, n_phys, max_blocks = _PAGED_SHAPES[t]
     q = sd((B, t, K, G, hd), jnp.bfloat16)
     tables, pos0 = sd((B, max_blocks), jnp.int32), sd((B,), jnp.int32)
     if pool == "bf16":
@@ -118,6 +132,8 @@ def test_paged_attention_compiles(one_chip, pool, t):
             q, k, v, bt, p, scale=0.088, k_scale_exp=ke, v_scale_exp=ve, kv_bits=bits)
         text = _compile_text(fn, q, kv, kv, ex, ex, tables, pos0)
     assert "tpu_custom_call" in text
+    name = "paged_attention" if pool == "bf16" else f"paged_attention_{pool}"
+    assert f"{name}_h{heads_per_step(K, t * G)}" in text  # the fold names the call
 
 
 @pytest.mark.parametrize("pool", ["bf16", "int4"])
